@@ -34,7 +34,7 @@ def run() -> list[Row]:
         if log_c <= 12:
             usk = time_call(
                 lambda: degree_count(srck, dstk, n_counters).block_until_ready(),
-                repeats=1, warmup=0,
+                repeats=1, warmup=1,
             )
             rows.append((f"fig04/pallas_interp/M={n_counters*4}B", usk, usk * 1e3 / (2 * ek)))
     return rows

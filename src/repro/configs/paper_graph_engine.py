@@ -1,12 +1,15 @@
 """The paper's own workload as an 11th config: a sharded PageRank-pull
 iteration + BFS frontier expansion over an RMAT-scale graph, distributed
 edge-parallel over the mesh (the graph-engine data path the scheduler
-controls). Dry-run-only at full scale (V=2^26, E=2^30)."""
+controls). Dry-run-only at full scale (V=2^26, E=2^30).
+
+Gathers and scatters with edge-sharded operands spell out their output
+sharding (``sharding_for``): an explicit-axis mesh refuses to infer it."""
 import jax
 import jax.numpy as jnp
 
 from ..launch.steps import CellProgram
-from ..sharding.context import constrain
+from ..sharding.context import sharding_for
 
 ARCH_ID = "paper-graph-engine"
 FAMILY = "graph"
@@ -19,8 +22,10 @@ def make_cell(shape: str, **_):
     if shape == "pr_iteration":
         def step(src, dst, rank, out_deg):
             contrib = jnp.where(out_deg > 0, rank / jnp.maximum(out_deg, 1), 0.0)
-            vals = constrain(jnp.take(contrib, src), ("edges",))
-            acc = jax.ops.segment_sum(vals, dst, num_segments=V)
+            vals = contrib.at[src].get(out_sharding=sharding_for(("edges",), src.shape))
+            acc = jnp.zeros((V,), vals.dtype).at[dst].add(
+                vals, out_sharding=sharding_for(("nodes",), (V,))
+            )
             return 0.15 / V + 0.85 * acc
 
         args = (
@@ -37,8 +42,15 @@ def make_cell(shape: str, **_):
         )
 
     def step(src, dst, visited, frontier):
-        active = constrain(jnp.take(frontier, src), ("edges",))
-        touched = jnp.zeros((V,), jnp.bool_).at[dst].max(active, mode="drop")
+        active = frontier.at[src].get(out_sharding=sharding_for(("edges",), src.shape))
+        # the bool OR of a scatter-max, as a set of True at active edges'
+        # targets: in JAX 0.9 `.at[].max` takes no out_sharding ("unexpected
+        # keyword argument 'out_sharding'") and without it the scatter raises
+        # ShardingTypeError ("out sharding could not be resolved
+        # unambiguously"); inactive edges index past V and are dropped
+        touched = jnp.zeros((V,), jnp.bool_).at[jnp.where(active, dst, V)].set(
+            True, mode="drop", out_sharding=sharding_for(("nodes",), (V,))
+        )
         new = touched & ~visited
         return visited | new, new
 

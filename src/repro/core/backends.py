@@ -21,15 +21,17 @@ onto three substrates:
   (modeled, measured) pairs).
 * :class:`PallasBackend` — lowers a package batch to a jitted
   SpMV / degree-count kernel call (``kernels/spmv``,
-  ``kernels/degree_count``; interpret mode on CPU, compiled on TPU). Gang
-  width maps to grid parallelism: the batch's tile range is cut into
-  ``step.workers`` contiguous grid slices — one per gang member (on real
-  hardware each slice is a core's grid; interpret mode runs them
-  sequentially, so the *measured* time is the serialized sum). Package
-  ranges are padded to kernel tile boundaries and the out-of-range lanes
-  masked off before the result is applied (unpadding), so results stay
-  exact. Algorithms without a kernel lowering (PR-push) fall back to the
-  inline path.
+  ``kernels/degree_count``). The platform picks the mode: compiled Mosaic
+  kernels on a TPU, the Pallas interpreter on ``cpu``; asking for the
+  other one raises. Gang width maps to grid slices: the batch's tile
+  range is cut into ``step.workers`` contiguous slices, one kernel launch
+  each, run back to back, so the *measured* time is the serialized sum.
+  Package ranges are padded to kernel tile boundaries and the
+  out-of-range lanes masked off before the result is applied (unpadding),
+  so results stay exact. Algorithms without a kernel lowering (PR-push)
+  fall back to the inline path; ``PallasBackend.lowerings`` counts the
+  lowering every prepared plan got, so a caller can tell a kernel-lowered
+  query from one that ran inline.
 
 The protocol splits *preparation* from *execution* deliberately:
 ``prepare`` may compile, build device tile tables, and warm the jit cache;
@@ -40,6 +42,7 @@ warm-up).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import time
 from typing import TYPE_CHECKING, Any, Protocol, runtime_checkable
@@ -261,15 +264,22 @@ class PallasBackend:
 
     Anything without a lowering (PR-push's unsorted scatter) runs the
     inline path — the backend is a superset, never a restriction.
+    ``lowerings`` counts prepared plans per lowering kind (``"pr_pull"``,
+    ``"bfs"``, ``"degree_count"`` or ``"inline"``).
 
-    ``interpret=True`` (default) runs the kernels through the Pallas
-    interpreter on CPU: numerically the real kernel, timed for real, just
-    not TPU-fast. On a TPU host pass ``interpret=False``."""
+    ``interpret=None`` (default) follows the platform: compiled kernels on
+    a TPU, the Pallas interpreter on ``cpu`` (numerically the real kernel,
+    timed for real, just not TPU-fast). An explicit value that contradicts
+    the platform raises: interpret mode on a TPU would silently measure the
+    interpreter, and compiled kernels cannot execute on ``cpu``."""
 
     name = "pallas"
 
-    def __init__(self, *, interpret: bool = True):
-        self.interpret = bool(interpret)
+    def __init__(self, *, interpret: bool | None = None):
+        from ..kernels.platform import resolve_interpret
+
+        self.interpret = resolve_interpret(interpret, executing=True)
+        self.lowerings: collections.Counter[str] = collections.Counter()
         self._memo = _PlanMemo()
         # graph-level device state, shared by every plan on the same graph:
         # raw tile tables under (gkey, "in"|"out"), and *whole warmed
@@ -317,22 +327,23 @@ class PallasBackend:
         )
         return src_chunks, dstl_chunks, DST_TILE
 
+    def staged_tables(self) -> list[tuple[str, tuple[int, ...], int]]:
+        """``(direction, shape, bytes)`` of every staged dst-tiled table
+        pair: ``"in"`` serves PR-pull, ``"out"`` BFS; bytes count both the
+        source and the local-target table."""
+        return [
+            (key[-1], tuple(h.src_chunks.shape), h.src_chunks.nbytes + h.dstl_chunks.nbytes)
+            for key, h in self._graph_tables.items()
+            if h.kind == "tables"
+        ]
+
     def _warm_spmv(self, handle: _PallasHandle) -> None:
         """Trigger the kernel's compile/trace outside any measured window."""
         import jax
         import jax.numpy as jnp
 
-        from ..kernels.spmv.spmv import spmv_pallas
-
         contrib = jnp.zeros((handle.num_vertices,), jnp.float32)
-        out = spmv_pallas(
-            handle.src_chunks[:1],
-            handle.dstl_chunks[:1],
-            contrib,
-            dst_tile=handle.dst_tile,
-            interpret=self.interpret,
-        )
-        jax.block_until_ready(out)
+        jax.block_until_ready(self._spmv_range(handle, contrib, 0, 1, 1, 0, 0))
 
     def prepare(
         self, executor: "QueryExecutor", prep: "PreparedIteration", shard: Any = None
@@ -356,6 +367,7 @@ class PallasBackend:
             if shared is not None:
                 # another session (or a previous prep of this one) already
                 # staged and warmed this (graph, kind, shard) — reuse it
+                self.lowerings[shared.kind] += 1
                 return self._memo.put(
                     DevicePlan(executor, prep, shared, shard=shard)
                 )
@@ -432,51 +444,53 @@ class PallasBackend:
             handle = _PallasHandle(kind="inline")
         if hkey is not None:
             self._graph_tables[hkey] = handle
+        self.lowerings[handle.kind] += 1
         return self._memo.put(DevicePlan(executor, prep, handle, shard=shard))
 
     # ---------------------------------------------------------- execution
     def _grid_slices(self, t0: int, t1: int, workers: int) -> list[tuple[int, int]]:
         """Cut tile range [t0, t1) into ≤ ``workers`` contiguous grid slices.
 
-        Each slice is one gang member's grid (a core's worth of sequential
-        grid steps on real hardware); the interpreter runs the slices back
-        to back, so measured time reflects the serialized work."""
+        Each slice is one gang member's grid and one kernel launch; the
+        launches run back to back (interpreted or compiled), so measured
+        time reflects the serialized work."""
         n = t1 - t0
         w = max(min(int(workers), n), 1)
         bounds = np.linspace(t0, t1, w + 1).round().astype(int)
         return [(int(a), int(b)) for a, b in zip(bounds[:-1], bounds[1:]) if b > a]
 
-    def _tile_slab(self, handle: _PallasHandle, a: int, b: int) -> tuple[Any, Any]:
-        """Device chunk tables for absolute dst tiles [a, b): the shard-local
-        slab when the range lies inside the plan's shard (the common case
-        under locality placement — the dispatch never touches other shards'
-        tables), the full tables otherwise (a drifted frontier stays exact)."""
+    def _tile_slab(self, handle: _PallasHandle, a: int, b: int) -> tuple[Any, Any, int]:
+        """Device chunk tables holding absolute dst tiles [a, b), and the
+        row of tile ``a`` in them: the shard-local slab when the range lies
+        inside the plan's shard (the common case under locality placement —
+        the dispatch never touches other shards' tables), the full tables
+        otherwise (a drifted frontier stays exact)."""
         if handle.shard_src is not None and a >= handle.tile_lo and b <= handle.tile_hi:
-            lo = handle.tile_lo
-            return handle.shard_src[a - lo : b - lo], handle.shard_dstl[a - lo : b - lo]
-        return handle.src_chunks[a:b], handle.dstl_chunks[a:b]
+            return handle.shard_src, handle.shard_dstl, a - handle.tile_lo
+        return handle.src_chunks, handle.dstl_chunks, a
 
     def _spmv_range(
-        self, handle: _PallasHandle, contrib, t0: int, t1: int, workers: int
+        self, handle: _PallasHandle, contrib, t0: int, t1: int, workers: int,
+        lo: int, hi: int,
     ):
         """Aggregate dst tiles [t0, t1) at gang width ``workers``; returns
-        the flat [.. (t1-t0)*tile] per-target sums."""
+        the [V] per-target sums, zero outside targets [lo, hi) (unpadding).
+
+        Window starts and bounds are traced, so each distinct slice length
+        compiles once, however many ranges it serves."""
         import jax.numpy as jnp
 
-        from ..kernels.spmv.spmv import spmv_pallas
+        from ..kernels.spmv.ops import spmv_window
 
-        outs = []
+        tile = handle.dst_tile
+        out = jnp.zeros((handle.src_chunks.shape[0] * tile,), jnp.float32)
         for a, b in self._grid_slices(t0, t1, workers):
-            src_chunks, dstl_chunks = self._tile_slab(handle, a, b)
-            out = spmv_pallas(
-                src_chunks,
-                dstl_chunks,
-                contrib,
-                dst_tile=handle.dst_tile,
-                interpret=self.interpret,
+            src_chunks, dstl_chunks, row = self._tile_slab(handle, a, b)
+            out = spmv_window(
+                out, src_chunks, dstl_chunks, contrib, row, a * tile, lo, hi,
+                n_tiles=b - a, dst_tile=tile, interpret=self.interpret,
             )
-            outs.append(out.reshape(-1))
-        return jnp.concatenate(outs) if len(outs) > 1 else outs[0]
+        return out[: handle.num_vertices]
 
     def _ranges(self, plan: DevicePlan, step: "ScheduleStep") -> list[tuple[int, int]]:
         """The batch's contiguous frontier-slot ranges."""
@@ -488,22 +502,13 @@ class PallasBackend:
         self, plan: DevicePlan, step: "ScheduleStep"
     ) -> None:
         import jax
-        import jax.numpy as jnp
 
         h = plan.handle
         ex = plan.executor
         tile = h.dst_tile
         for lo, hi in self._ranges(plan, step):
             t0, t1 = lo // tile, -(-hi // tile)
-            flat = self._spmv_range(h, ex.contrib, t0, t1, step.workers)
-            # unpad: mask lanes outside [lo, hi) before applying the partial
-            ids = t0 * tile + jnp.arange(flat.shape[0], dtype=jnp.int32)
-            masked = jnp.where((ids >= lo) & (ids < hi), flat, 0.0)
-            agg = (
-                jnp.zeros((h.num_vertices,), flat.dtype)
-                .at[ids]
-                .set(masked, mode="drop")
-            )
+            agg = self._spmv_range(h, ex.contrib, t0, t1, step.workers, lo, hi)
             edges = float(h.edge_prefix[hi] - h.edge_prefix[lo])
             jax.block_until_ready(agg)
             ex.apply_pull_aggregate(agg, lo, hi, edges)
@@ -516,15 +521,14 @@ class PallasBackend:
         ex = plan.executor
         n_tiles = h.src_chunks.shape[0]
         for lo, hi in self._ranges(plan, step):
-            members = ex.frontier_slot_vertices(lo, hi)
-            contrib = (
-                jnp.zeros((h.num_vertices,), jnp.float32)
-                .at[jnp.asarray(members)]
-                .set(1.0, mode="drop")
-            )
+            # the frontier indicator is built on the host: a device scatter
+            # would compile once per distinct member count
+            contrib = np.zeros((h.num_vertices,), np.float32)
+            contrib[ex.frontier_slot_vertices(lo, hi)] = 1.0
             # members' out-neighbours may land in any target tile → full grid
-            counts = self._spmv_range(h, contrib, 0, n_tiles, step.workers)
-            counts = counts[: h.num_vertices]
+            counts = self._spmv_range(
+                h, jnp.asarray(contrib), 0, n_tiles, step.workers, 0, h.num_vertices
+            )
             jax.block_until_ready(counts)
             ex.apply_expansion(counts, lo, hi)
 

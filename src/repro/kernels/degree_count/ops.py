@@ -22,7 +22,7 @@ def degree_count(
     dst: jnp.ndarray,
     num_counters: int,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Count edge-endpoint occurrences (src and dst) in a counter array."""
     ids = jnp.concatenate([src, dst]).astype(jnp.int32) % num_counters

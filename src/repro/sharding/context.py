@@ -32,13 +32,21 @@ def active() -> tuple[Mesh, dict] | None:
     return _ACTIVE.get()
 
 
-def constrain(x: Any, axes: tuple | None):
+def sharding_for(axes: tuple | None, shape: tuple[int, ...]) -> NamedSharding | None:
+    """The active context's sharding for an array of ``shape`` with these
+    logical axes; ``None`` outside a context (single device)."""
     ctx = _ACTIVE.get()
     if ctx is None or axes is None:
-        return x
+        return None
     mesh, rules = ctx
-    spec = spec_for(tuple(axes), x.shape, mesh, rules)
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+    return NamedSharding(mesh, spec_for(tuple(axes), shape, mesh, rules))
+
+
+def constrain(x: Any, axes: tuple | None):
+    sharding = sharding_for(axes, x.shape)
+    if sharding is None:
+        return x
+    return jax.lax.with_sharding_constraint(x, sharding)
 
 
 # --- scan unrolling for dry-run cost accounting ---------------------------

@@ -74,7 +74,7 @@ def test_resolve_backend_specs():
 
 
 def test_backends_satisfy_protocol():
-    for b in (ModeledBackend(), InlineBackend(), PallasBackend()):
+    for b in (ModeledBackend(), InlineBackend(), PallasBackend(interpret=True)):
         assert isinstance(b, ExecutionBackend)
 
 
@@ -157,7 +157,7 @@ def pallas_graph():
 def test_pallas_pagerank_pull_matches_reference(pallas_graph):
     iters = 5
     ref = pagerank_reference(pallas_graph, iters=iters)
-    eng = _engine("pallas")
+    eng = _engine(PallasBackend(interpret=True))
     ex = PageRankExecutor(pallas_graph, mode="pull", max_iters=iters, tol=0)
     rec = _run_one(eng, ex)
     np.testing.assert_allclose(ex.result(), ref, rtol=2e-4, atol=1e-8)
@@ -168,14 +168,14 @@ def test_pallas_pagerank_pull_matches_reference(pallas_graph):
 def test_pallas_bfs_matches_reference(pallas_graph):
     deg = np.asarray(pallas_graph.out_degrees())
     src = int(np.argmax(deg))
-    eng = _engine("pallas")
+    eng = _engine(PallasBackend(interpret=True))
     ex = BFSExecutor(pallas_graph, src)
     _run_one(eng, ex)
     assert np.array_equal(ex.result(), bfs_reference(pallas_graph, src))
 
 
 def test_pallas_degree_count_matches_reference(pallas_graph):
-    eng = _engine("pallas")
+    eng = _engine(PallasBackend(interpret=True))
     ex = DegreeCountExecutor(pallas_graph)
     _run_one(eng, ex)
     ref = degree_count_reference(
@@ -186,15 +186,62 @@ def test_pallas_degree_count_matches_reference(pallas_graph):
 
 def test_pallas_falls_back_inline_without_lowering(pallas_graph):
     """PR-push has no kernel lowering (unsorted scatter) — the backend runs
-    it on the inline path and the result still matches the oracle."""
+    it on the inline path, says so in ``lowerings``, and the result still
+    matches the oracle."""
     iters = 5
-    eng = _engine("pallas")
+    backend = PallasBackend(interpret=True)
     ex = PageRankExecutor(pallas_graph, mode="push", max_iters=iters, tol=0)
-    _run_one(eng, ex)
+    _run_one(_engine(backend), ex)
     np.testing.assert_allclose(
         ex.result(), pagerank_reference(pallas_graph, iters=iters),
         rtol=2e-4, atol=1e-8,
     )
+    assert set(backend.lowerings) == {"inline"}
+
+
+def test_pallas_lowerings_name_the_kernel_each_plan_got(pallas_graph):
+    backend = PallasBackend(interpret=True)
+    eng = _engine(backend)
+    _run_one(eng, PageRankExecutor(pallas_graph, mode="pull", max_iters=2, tol=0))
+    _run_one(eng, DegreeCountExecutor(pallas_graph))
+    assert backend.lowerings["pr_pull"] > 0 and backend.lowerings["degree_count"] > 0
+    assert backend.lowerings["inline"] == 0
+
+
+def test_pallas_staged_tables_report_what_is_on_the_device(pallas_graph):
+    """PR-pull stages the in-edge tables, BFS the out-edge tables, once per
+    graph however many plans share them; bytes cover both tables."""
+    backend = PallasBackend(interpret=True)
+    eng = _engine(backend)
+    assert backend.staged_tables() == []
+    _run_one(eng, PageRankExecutor(pallas_graph, mode="pull", max_iters=2, tol=0))
+    _run_one(eng, PageRankExecutor(pallas_graph, mode="pull", max_iters=2, tol=0))
+    _run_one(eng, BFSExecutor(pallas_graph, 0))
+    staged = backend.staged_tables()
+    assert sorted(d for d, _, _ in staged) == ["in", "out"]
+    for _, (tiles, chunk), nbytes in staged:
+        assert tiles * 512 >= pallas_graph.num_vertices
+        assert nbytes == 2 * tiles * chunk * 4
+
+
+def test_pallas_interpret_mode_follows_the_platform(monkeypatch):
+    """On cpu the default is the interpreter and compiled kernels are
+    refused; on a TPU the default is compiled and the interpreter refused."""
+    import jax
+
+    from repro.kernels.platform import resolve_interpret
+
+    assert PallasBackend().interpret is True
+    with pytest.raises(ValueError, match="cpu"):
+        PallasBackend(interpret=False)
+    # kernel entry points may still be lowered for a described TPU from cpu
+    assert resolve_interpret(False) is False
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    assert PallasBackend().interpret is False
+    with pytest.raises(ValueError, match="tpu"):
+        PallasBackend(interpret=True)
 
 
 def test_pallas_results_stable_across_gang_widths(pallas_graph):
@@ -202,7 +249,7 @@ def test_pallas_results_stable_across_gang_widths(pallas_graph):
     one: single-query (wide gang) and a contended 4-session run (narrow,
     stolen, re-sliced gangs) produce identical PageRank ranks."""
     iters = 3
-    solo = _engine("pallas")
+    solo = _engine(PallasBackend(interpret=True))
     ex_solo = PageRankExecutor(pallas_graph, mode="pull", max_iters=iters, tol=0)
     _run_one(solo, ex_solo)
 
@@ -214,7 +261,10 @@ def test_pallas_results_stable_across_gang_widths(pallas_graph):
         return ex
 
     eng = MultiQueryEngine(
-        XEON_E5_2660V4, pool_capacity=4, policy="scheduler", backend="pallas"
+        XEON_E5_2660V4,
+        pool_capacity=4,
+        policy="scheduler",
+        backend=PallasBackend(interpret=True),
     )
     eng.run_sessions(
         mk, sessions=4, queries_per_session=1, config=EngineConfig(steal=True)
@@ -293,7 +343,7 @@ def test_pallas_measurements_populate_width_table(pallas_graph):
         pool_capacity=8,
         policy="scheduler",
         feedback=fb,
-        backend="pallas",
+        backend=PallasBackend(interpret=True),
     )
     rep = eng.run_sessions(
         _mixed_mk(pallas_graph),

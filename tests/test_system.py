@@ -2,6 +2,7 @@
 through the full engine, contention-driven selective sequential execution,
 and multi-device sharded execution parity (subprocess with forced devices)."""
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -93,8 +94,11 @@ def test_sharded_execution_parity_subprocess(tmp_path):
 
         @jax.jit
         def expand(visited, frontier):
-            active = jnp.take(frontier, src)
-            touched = jnp.zeros((V,), jnp.bool_).at[dst].max(active, mode="drop")
+            active = frontier.at[src].get(out_sharding=esh)
+            # scatter-max takes no out_sharding: set True at active targets
+            touched = jnp.zeros((V,), jnp.bool_).at[jnp.where(active, dst, V)].set(
+                True, mode="drop", out_sharding=vsh
+            )
             new = touched & ~visited
             return visited | new, new
 
@@ -103,10 +107,11 @@ def test_sharded_execution_parity_subprocess(tmp_path):
         visited = jax.device_put(visited, vsh); frontier = jax.device_put(frontier, vsh)
         level = np.full(V, -1); level[5] = 0
         depth = 0
-        while bool(frontier.any()):
-            depth += 1
-            visited, frontier = expand(visited, frontier)
-            level[np.asarray(frontier)] = depth
+        with jax.set_mesh(mesh):
+            while bool(frontier.any()):
+                depth += 1
+                visited, frontier = expand(visited, frontier)
+                level[np.asarray(frontier)] = depth
         ref = bfs_reference(g, 5)
         assert np.array_equal(level, ref), "sharded BFS != reference"
         print(json.dumps({"ok": True, "devices": len(jax.devices())}))
@@ -114,11 +119,14 @@ def test_sharded_execution_parity_subprocess(tmp_path):
     )
     p = tmp_path / "sharded_bfs.py"
     p.write_text(script)
+    # a CPU-only child on forced host devices: it never loads the TPU
+    # runtime, so it cannot contend with a parent that holds the chip
     r = subprocess.run(
         [sys.executable, str(p)],
         capture_output=True, text=True,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
+        env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
         cwd=str(Path(__file__).resolve().parent.parent),
+        timeout=300,
     )
     assert r.returncode == 0, r.stderr[-2000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])
