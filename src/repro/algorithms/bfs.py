@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.descriptors import BFS_TOP_DOWN
+from ..core.tracing import span
 from ..graph.structure import Graph, GraphStats
 from .common import EdgeArrays, compact_frontier, member_mask_from_slots, merge_ranges
 
@@ -197,7 +198,9 @@ class BFSExecutor:
         self._depth += 1
         self._covered = 0
         self._frontier_host = None
-        if int(self._n_frontier) == 0:
+        with span("mq.sync"):
+            empty = int(self._n_frontier) == 0
+        if empty:
             self._done = True
 
     def edges_traversed(self) -> float:
@@ -214,8 +217,9 @@ class BFSExecutor:
     def frontier_slot_vertices(self, lo: int, hi: int) -> np.ndarray:
         """Vertex ids occupying compacted-frontier slots [lo, hi)."""
         if self._frontier_host is None:
-            n = int(self._n_frontier)
-            self._frontier_host = np.asarray(self._frontier_list)[:n]
+            with span("mq.sync"):
+                n = int(self._n_frontier)
+                self._frontier_host = np.asarray(self._frontier_list)[:n]
         return self._frontier_host[lo:hi]
 
     def apply_expansion(self, counts: jnp.ndarray, lo: int, hi: int) -> None:

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.descriptors import PR_PULL, PR_PUSH
+from ..core.tracing import span
 from ..graph.structure import Graph, GraphStats
 from .common import EdgeArrays, merge_ranges
 
@@ -169,7 +170,8 @@ class PageRankExecutor:
             self._acc, self._dangling, self.damping,
             num_vertices=self._ea.num_vertices,
         )
-        delta = float(jnp.abs(new_rank - self._rank).sum())
+        with span("mq.sync"):
+            delta = float(jnp.abs(new_rank - self._rank).sum())
         self._rank = new_rank
         self._acc = jnp.zeros_like(self._acc)
         self._contrib, self._dangling = _prepare_contrib(

@@ -49,6 +49,8 @@ from typing import TYPE_CHECKING, Any, Protocol, runtime_checkable
 
 import numpy as np
 
+from .tracing import span
+
 if TYPE_CHECKING:  # pragma: no cover - annotation-only imports (no cycles)
     from .autotuner import PreparedIteration
     from .scheduler import ScheduleStep
@@ -486,10 +488,11 @@ class PallasBackend:
         out = jnp.zeros((handle.src_chunks.shape[0] * tile,), jnp.float32)
         for a, b in self._grid_slices(t0, t1, workers):
             src_chunks, dstl_chunks, row = self._tile_slab(handle, a, b)
-            out = spmv_window(
-                out, src_chunks, dstl_chunks, contrib, row, a * tile, lo, hi,
-                n_tiles=b - a, dst_tile=tile, interpret=self.interpret,
-            )
+            with span("mq.launch"):
+                out = spmv_window(
+                    out, src_chunks, dstl_chunks, contrib, row, a * tile, lo, hi,
+                    n_tiles=b - a, dst_tile=tile, interpret=self.interpret,
+                )
         return out[: handle.num_vertices]
 
     def _ranges(self, plan: DevicePlan, step: "ScheduleStep") -> list[tuple[int, int]]:
@@ -510,8 +513,10 @@ class PallasBackend:
             t0, t1 = lo // tile, -(-hi // tile)
             agg = self._spmv_range(h, ex.contrib, t0, t1, step.workers, lo, hi)
             edges = float(h.edge_prefix[hi] - h.edge_prefix[lo])
-            jax.block_until_ready(agg)
-            ex.apply_pull_aggregate(agg, lo, hi, edges)
+            with span("mq.sync"):
+                jax.block_until_ready(agg)
+            with span("mq.apply"):
+                ex.apply_pull_aggregate(agg, lo, hi, edges)
 
     def _execute_bfs(self, plan: DevicePlan, step: "ScheduleStep") -> None:
         import jax
@@ -523,14 +528,18 @@ class PallasBackend:
         for lo, hi in self._ranges(plan, step):
             # the frontier indicator is built on the host: a device scatter
             # would compile once per distinct member count
-            contrib = np.zeros((h.num_vertices,), np.float32)
-            contrib[ex.frontier_slot_vertices(lo, hi)] = 1.0
+            with span("mq.host_prep"):
+                contrib = np.zeros((h.num_vertices,), np.float32)
+                contrib[ex.frontier_slot_vertices(lo, hi)] = 1.0
+                contrib = jnp.asarray(contrib)
             # members' out-neighbours may land in any target tile → full grid
             counts = self._spmv_range(
-                h, jnp.asarray(contrib), 0, n_tiles, step.workers, 0, h.num_vertices
+                h, contrib, 0, n_tiles, step.workers, 0, h.num_vertices
             )
-            jax.block_until_ready(counts)
-            ex.apply_expansion(counts, lo, hi)
+            with span("mq.sync"):
+                jax.block_until_ready(counts)
+            with span("mq.apply"):
+                ex.apply_expansion(counts, lo, hi)
 
     def _execute_degree_count(
         self, plan: DevicePlan, step: "ScheduleStep"
@@ -548,18 +557,24 @@ class PallasBackend:
         for lo, hi in self._ranges(plan, step):
             # both endpoints of every edge in [lo, hi), padded to the
             # kernel's edge-block boundary with the -1 no-match sentinel
-            ids = h.ids_pad[:, lo:hi].reshape(-1)
-            total = np.zeros((h.num_vertices,), np.int32)
+            with span("mq.host_prep"):
+                ids = h.ids_pad[:, lo:hi].reshape(-1)
+                total = np.zeros((h.num_vertices,), np.int32)
             for a, b in self._grid_slices(0, ids.size, step.workers):
-                chunk = ids[a:b]
-                pad = -(-chunk.size // EDGE_BLOCK) * EDGE_BLOCK
-                padded = np.full((pad,), -1, np.int32)
-                padded[: chunk.size] = chunk
-                counts = degree_count_pallas(
-                    jnp.asarray(padded), h.num_vertices, interpret=self.interpret
-                )
-                total += np.asarray(jax.block_until_ready(counts))
-            ex.apply_counts(total[: int(ex.num_counters)], lo, hi)
+                with span("mq.host_prep"):
+                    chunk = ids[a:b]
+                    pad = -(-chunk.size // EDGE_BLOCK) * EDGE_BLOCK
+                    padded = np.full((pad,), -1, np.int32)
+                    padded[: chunk.size] = chunk
+                    padded = jnp.asarray(padded)
+                with span("mq.launch"):
+                    counts = degree_count_pallas(
+                        padded, h.num_vertices, interpret=self.interpret
+                    )
+                with span("mq.sync"):
+                    total += np.asarray(jax.block_until_ready(counts))
+            with span("mq.apply"):
+                ex.apply_counts(total[: int(ex.num_counters)], lo, hi)
 
     def execute(
         self, plan: DevicePlan, step: "ScheduleStep", modeled_ns: float = 0.0

@@ -81,6 +81,7 @@ from .scheduler import (
 )
 from .stealing import StealRegistry, graph_identity
 from .timeline import step_integral, step_mean
+from .tracing import span
 from ..graph.partition import GraphPartition
 
 if TYPE_CHECKING:  # pragma: no cover - annotation-only import (no cycle)
@@ -158,6 +159,11 @@ class QueryRecord:
     def latency_ns(self) -> float:
         """Modeled end-to-end latency including admission wait."""
         return max(self.finished_ns - self.submitted_ns, 0.0)
+
+
+def _ids(record: QueryRecord | None) -> dict[str, int]:
+    """A span's ``session``/``query`` args, where the query is known."""
+    return {} if record is None else {"session": record.session, "query": record.query}
 
 
 def _percentiles(latencies_ns: Sequence[float]) -> dict[str, float]:
@@ -846,6 +852,8 @@ class MultiQueryEngine:
         step: ScheduleStep,
         modeled_ns: float = 0.0,
         shard: Any = None,
+        *,
+        record: QueryRecord | None = None,
     ) -> float:
         """Dispatch one schedule step through the execution backend; returns
         the backend's measured ns.
@@ -859,12 +867,14 @@ class MultiQueryEngine:
         domain's :class:`~..graph.partition.GraphShard`: substrates that
         stage per-shard device tables (PallasBackend) dispatch against the
         shard-local slices; the two-argument call is kept for duck-typed
-        backends that predate the shard axis."""
-        if shard is not None:
-            plan = self.backend.prepare(executor, prep, shard)
-        else:
-            plan = self.backend.prepare(executor, prep)
-        return float(self.backend.execute(plan, step, modeled_ns=modeled_ns))
+        backends that predate the shard axis. ``record`` names the query
+        in the step's ``mq.dispatch`` span."""
+        with span("mq.dispatch", **_ids(record)):
+            if shard is not None:
+                plan = self.backend.prepare(executor, prep, shard)
+            else:
+                plan = self.backend.prepare(executor, prep)
+            return float(self.backend.execute(plan, step, modeled_ns=modeled_ns))
 
     def _step_cost_ns(
         self, desc: AlgorithmDescriptor, prep: PreparedIteration, step: ScheduleStep
@@ -909,12 +919,18 @@ class MultiQueryEngine:
         scheduler: PackageScheduler,
     ) -> ScheduleTrace:
         """Execute one full iteration synchronously (run_query path)."""
-        bounds = self._decide(prep)
-        srun = scheduler.begin(prep.packages, bounds)
+        ids = _ids(record)
+        with span("mq.decide", **ids):
+            bounds = self._decide(prep)
+            srun = scheduler.begin(prep.packages, bounds)
         modeled = 0.0
         measured = 0.0
         try:
-            while (step := srun.next_step()) is not None:
+            while True:
+                with span("mq.decide", **ids):
+                    step = srun.next_step()
+                if step is None:
+                    break
                 if step.mode == "stalled":
                     # no event loop to wait in: a synchronous iteration on a
                     # drained pool cannot proceed without phantom workers
@@ -922,18 +938,22 @@ class MultiQueryEngine:
                         "worker pool exhausted: a schedule step must hold >= 1 worker"
                     )
                 step_modeled = self._step_cost_ns(executor.desc, prep, step)
-                step_measured = self._execute_step(executor, prep, step, step_modeled)
+                step_measured = self._execute_step(
+                    executor, prep, step, step_modeled, record=record
+                )
                 measured += step_measured
                 modeled += step_modeled
-                self._observe_width(
-                    executor.desc.name,
-                    step.workers if step.mode == "parallel" else 1,
-                    step_modeled,
-                    step_measured,
-                )
+                with span("mq.account", **ids):
+                    self._observe_width(
+                        executor.desc.name,
+                        step.workers if step.mode == "parallel" else 1,
+                        step_modeled,
+                        step_measured,
+                    )
         finally:
             srun.close()
-        self._account_iteration(executor, record, srun.trace, modeled, measured)
+        with span("mq.account", **ids):
+            self._account_iteration(executor, record, srun.trace, modeled, measured)
         return srun.trace
 
     # ------------------------------------------------------------------
@@ -942,7 +962,9 @@ class MultiQueryEngine:
 
         Updates ``record`` with measured/modeled time and decision traces.
         """
-        executor.start()
+        ids = _ids(record)
+        with span("mq.query_start", **ids):
+            executor.start()
         scheduler = PackageScheduler(
             self.pool,
             seq_package_limit=self.seq_package_limit,
@@ -950,10 +972,11 @@ class MultiQueryEngine:
         )
         prep: PreparedIteration | None = None
         while not executor.finished():
-            fsize, fdeg, unvisited = executor.frontier()
-            if fsize <= 0:
-                break
-            prep = self._prepare(executor, prep, fsize, fdeg, unvisited)
+            with span("mq.prepare", **ids):
+                fsize, fdeg, unvisited = executor.frontier()
+                if fsize <= 0:
+                    break
+                prep = self._prepare(executor, prep, fsize, fdeg, unvisited)
             self._run_iteration(executor, record, prep, scheduler)
         record.edges = float(executor.edges_traversed())
 
@@ -1309,32 +1332,33 @@ class MultiQueryEngine:
             """Move the session to its next query; False → session exhausted."""
             if st.next_query >= queries_per_session:
                 return False
-            st.executor = make_executor(st.sid, st.next_query)
-            st.executor.start()
-            # stable dataset identity (not id()): two sessions that loaded
-            # the same graph into distinct objects still group for steal
-            # locality and gang fusion
-            st.graph_key = graph_identity(st.executor)
-            st.record = QueryRecord(
-                session=st.sid,
-                query=st.next_query,
-                algorithm=st.executor.desc.name,
-                priority=st.priority,
-            )
-            if dynamic:
-                # pin stamp: the snapshot this query starts on is the one it
-                # finishes on — later publishes must not touch it (the fig22
-                # trace-level assertion reads this back per record)
-                st.record.graph_epoch = getattr(
-                    getattr(st.executor, "graph", None), "epoch", None
+            with span("mq.query_start", session=st.sid, query=st.next_query):
+                st.executor = make_executor(st.sid, st.next_query)
+                st.executor.start()
+                # stable dataset identity (not id()): two sessions that loaded
+                # the same graph into distinct objects still group for steal
+                # locality and gang fusion
+                st.graph_key = graph_identity(st.executor)
+                st.record = QueryRecord(
+                    session=st.sid,
+                    query=st.next_query,
+                    algorithm=st.executor.desc.name,
+                    priority=st.priority,
                 )
-            # closed loop within a session: the next query is submitted the
-            # moment the previous one finishes. The first query inherits the
-            # session's arrival time so admission wait counts into latency.
-            st.record.submitted_ns = float(arrival_ns[st.sid]) if st.next_query == 0 else t
-            records.append(st.record)
-            st.prep = None
-            st.next_query += 1
+                if dynamic:
+                    # pin stamp: the snapshot this query starts on is the one it
+                    # finishes on — later publishes must not touch it (the fig22
+                    # trace-level assertion reads this back per record)
+                    st.record.graph_epoch = getattr(
+                        getattr(st.executor, "graph", None), "epoch", None
+                    )
+                # closed loop within a session: the next query is submitted the
+                # moment the previous one finishes. The first query inherits the
+                # session's arrival time so admission wait counts into latency.
+                st.record.submitted_ns = float(arrival_ns[st.sid]) if st.next_query == 0 else t
+                records.append(st.record)
+                st.prep = None
+                st.next_query += 1
             return True
 
         def _finish_query(st: _SessionState, t: float) -> None:
@@ -1533,13 +1557,14 @@ class MultiQueryEngine:
                 if cross and migration_penalty:
                     step_ns = cross_domain_cost_ns(self.hw, step_ns)
                 measured = self._execute_step(
-                    victim.executor, victim.prep, step, step_ns
+                    victim.executor, victim.prep, step, step_ns, record=victim.record
                 )
                 # stolen batches run at a width the victim never planned for:
                 # exactly the observations the width table exists to capture
-                self._observe_width(
-                    victim.executor.desc.name, usable, step_ns, measured
-                )
+                with span("mq.account", **_ids(victim.record)):
+                    self._observe_width(
+                        victim.executor.desc.name, usable, step_ns, measured
+                    )
                 thief.steal = _StealJob(
                     victim=victim,
                     run=entry.run,
@@ -1659,15 +1684,17 @@ class MultiQueryEngine:
             for share in shares:
                 slot, _, local_ids = share[0], share[1], share[2]
                 s_step = ScheduleStep(local_ids, mode, workers)
+                rec = slot.payload.record
                 share[4] = self._execute_step(
-                    slot.payload.executor, slot.prep, s_step, share[3]
+                    slot.payload.executor, slot.prep, s_step, share[3], record=rec
                 )
                 # split-back commits carry exact per-member (width, modeled,
                 # measured) tuples — feed the width table here so members'
                 # next preparations know how the gang width really performed
-                self._observe_width(
-                    slot.payload.executor.desc.name, t_eff, share[3], share[4]
-                )
+                with span("mq.account", **_ids(rec)):
+                    self._observe_width(
+                        slot.payload.executor.desc.name, t_eff, share[3], share[4]
+                    )
             return shares, total
 
         def _finalize_member(slot: FusionMember, t: float) -> None:
@@ -1678,9 +1705,10 @@ class MultiQueryEngine:
             st.fused_member = None
             assert st.executor is not None and st.record is not None
             st.record.fused_packages += slot.trace.fused_packages
-            self._account_iteration(
-                st.executor, st.record, slot.trace, slot.modeled_ns, slot.measured_ns
-            )
+            with span("mq.account", **_ids(st.record)):
+                self._account_iteration(
+                    st.executor, st.record, slot.trace, slot.modeled_ns, slot.measured_ns
+                )
             _push(t, EV_STEP, st)
 
         def _launch_group(
@@ -1865,7 +1893,8 @@ class MultiQueryEngine:
                 if slot.complete:
                     _finalize_member(slot, t)
             pre_preempt = run.trace.preempted
-            step = run.next_step()
+            with span("mq.decide"):
+                step = run.next_step()
             if step is None:
                 if registry is not None:
                     registry.withdraw(driver.sid)
@@ -1902,13 +1931,14 @@ class MultiQueryEngine:
             shares, total = _execute_fused_batch(
                 group, step.batch, step.mode, step.workers
             )
-            driver.pending_shares = [
-                (s[0], s[1], s[2], step.mode, step.workers, s[3], s[4])
-                for s in shares
-            ]
-            _sample(t)
-            _push(t + total, EV_STEP, driver)
-            _wake_stalled(t)
+            with span("mq.account"):
+                driver.pending_shares = [
+                    (s[0], s[1], s[2], step.mode, step.workers, s[3], s[4])
+                    for s in shares
+                ]
+                _sample(t)
+                _push(t + total, EV_STEP, driver)
+                _wake_stalled(t)
 
         try:
             while heap:
@@ -2109,7 +2139,8 @@ class MultiQueryEngine:
                                 st = None
                                 break
                             continue
-                        fsize, fdeg, unvisited = ex.frontier()
+                        with span("mq.prepare", **_ids(st.record)):
+                            fsize, fdeg, unvisited = ex.frontier()
                         if fsize <= 0:
                             _finish_query(st, t)
                             if can_mid_steal and _try_steal(st, t):
@@ -2123,133 +2154,136 @@ class MultiQueryEngine:
                     assert rec is not None
                     if rec.started_ns == 0.0 and rec.iterations == 0:
                         rec.started_ns = t
-                    # multi-domain: preparation doubles as the placement
-                    # decision point — the partition hands the plan its
-                    # per-domain degree mass, from the exact frontier when
-                    # the executor exposes one (data-driven), or the static
-                    # degree mass (topology-centric whole-graph frontiers)
-                    part = _partition_for(st)
-                    fvert = None
-                    if part is not None:
-                        fv_fn = getattr(ex, "frontier_vertices", None)
-                        if callable(fv_fn):
-                            fvert = fv_fn()
-                    if (
-                        fusing is not None
-                        and st.prep is None
-                        and st.graph_key is not None
-                        and ex.desc.kind == "topology"
-                    ):
-                        # amortized preparation: co-located topology-centric
-                        # queries (same graph, same algorithm, same frontier)
-                        # share one sampling/packaging pass — the gang
-                        # prepares once, not once per member. Data-driven
-                        # frontiers differ in content per session, so they
-                        # keep their own preparation. The key covers every
-                        # prepare_iteration input: a cheap degree fingerprint
-                        # guards against an executor whose equal-size first
-                        # frontier carries different degrees per session
-                        fp = (
-                            None
-                            if fdeg is None
-                            else (int(len(fdeg)), int(np.asarray(fdeg).sum()))
-                        )
-                        ck = (
-                            st.graph_key,
-                            ex.desc.name,
-                            fsize,
-                            float(unvisited),
-                            fp,
-                            self.pool.capacity,
-                        )
-                        # corrections evolve: a prep computed under an older
-                        # width table must not serve a newer one. Preparation
-                        # consumes the feedback table ONLY through
-                        # width_ratio(algorithm, t) at the sweep's candidate
-                        # widths, so that tuple is the exact staleness stamp:
-                        # the cached *value* is replaced in place when (and
-                        # only when) a ratio the plan depends on actually
-                        # moved — an observation-counter stamp would
-                        # invalidate on every executed step and silently
-                        # negate the shared-prep amortization, and stamping
-                        # the *key* would strand dead entries
-                        ver = (
-                            self._width_signature(ex.desc.name)
-                            if self._width_fb_on
-                            else None
-                        )
-                        if dynamic:
-                            # snapshot-generation stamp, same mechanism as
-                            # the width-ratio signature: a prep computed
-                            # against one epoch's topology is never served
-                            # across an epoch boundary. The epoch-qualified
-                            # ``graph_key`` in ``ck`` already separates
-                            # snapshots; the stamp keeps the invariant even
-                            # for executors whose identity degenerates to
-                            # ``id(graph)`` (no ``.key``), and is what the
-                            # epoch property suite drives directly
+                    with span("mq.prepare", **_ids(rec)):
+                        # multi-domain: preparation doubles as the placement
+                        # decision point — the partition hands the plan its
+                        # per-domain degree mass, from the exact frontier when
+                        # the executor exposes one (data-driven), or the static
+                        # degree mass (topology-centric whole-graph frontiers)
+                        part = _partition_for(st)
+                        fvert = None
+                        if part is not None:
+                            fv_fn = getattr(ex, "frontier_vertices", None)
+                            if callable(fv_fn):
+                                fvert = fv_fn()
+                        if (
+                            fusing is not None
+                            and st.prep is None
+                            and st.graph_key is not None
+                            and ex.desc.kind == "topology"
+                        ):
+                            # amortized preparation: co-located topology-centric
+                            # queries (same graph, same algorithm, same frontier)
+                            # share one sampling/packaging pass — the gang
+                            # prepares once, not once per member. Data-driven
+                            # frontiers differ in content per session, so they
+                            # keep their own preparation. The key covers every
+                            # prepare_iteration input: a cheap degree fingerprint
+                            # guards against an executor whose equal-size first
+                            # frontier carries different degrees per session
+                            fp = (
+                                None
+                                if fdeg is None
+                                else (int(len(fdeg)), int(np.asarray(fdeg).sum()))
+                            )
+                            ck = (
+                                st.graph_key,
+                                ex.desc.name,
+                                fsize,
+                                float(unvisited),
+                                fp,
+                                self.pool.capacity,
+                            )
+                            # corrections evolve: a prep computed under an older
+                            # width table must not serve a newer one. Preparation
+                            # consumes the feedback table ONLY through
+                            # width_ratio(algorithm, t) at the sweep's candidate
+                            # widths, so that tuple is the exact staleness stamp:
+                            # the cached *value* is replaced in place when (and
+                            # only when) a ratio the plan depends on actually
+                            # moved — an observation-counter stamp would
+                            # invalidate on every executed step and silently
+                            # negate the shared-prep amortization, and stamping
+                            # the *key* would strand dead entries
                             ver = (
-                                ver,
-                                getattr(
-                                    getattr(ex, "graph", None), "epoch", None
-                                ),
+                                self._width_signature(ex.desc.name)
+                                if self._width_fb_on
+                                else None
                             )
-                        cached = prep_cache.get(ck)
-                        if cached is None or cached[0] != ver:
-                            # topology-centric plans carry the partition's
-                            # *static* degree mass — identical per graph, so
-                            # the shared cache stays valid across sessions
-                            cached = (
-                                ver,
-                                self._prepare(
-                                    ex, None, fsize, fdeg, unvisited, partition=part
-                                ),
+                            if dynamic:
+                                # snapshot-generation stamp, same mechanism as
+                                # the width-ratio signature: a prep computed
+                                # against one epoch's topology is never served
+                                # across an epoch boundary. The epoch-qualified
+                                # ``graph_key`` in ``ck`` already separates
+                                # snapshots; the stamp keeps the invariant even
+                                # for executors whose identity degenerates to
+                                # ``id(graph)`` (no ``.key``), and is what the
+                                # epoch property suite drives directly
+                                ver = (
+                                    ver,
+                                    getattr(
+                                        getattr(ex, "graph", None), "epoch", None
+                                    ),
+                                )
+                            cached = prep_cache.get(ck)
+                            if cached is None or cached[0] != ver:
+                                # topology-centric plans carry the partition's
+                                # *static* degree mass — identical per graph, so
+                                # the shared cache stays valid across sessions
+                                cached = (
+                                    ver,
+                                    self._prepare(
+                                        ex, None, fsize, fdeg, unvisited, partition=part
+                                    ),
+                                )
+                                prep_cache[ck] = cached
+                            st.prep = cached[1]
+                        else:
+                            st.prep = self._prepare(
+                                ex,
+                                st.prep,
+                                fsize,
+                                fdeg,
+                                unvisited,
+                                partition=part,
+                                frontier_vertices=fvert,
                             )
-                            prep_cache[ck] = cached
-                        st.prep = cached[1]
-                    else:
-                        st.prep = self._prepare(
-                            ex,
-                            st.prep,
-                            fsize,
-                            fdeg,
-                            unvisited,
-                            partition=part,
-                            frontier_vertices=fvert,
-                        )
-                    _place(st)
-                    bounds = self._decide(st.prep)
-                    if (
-                        fusing is not None
-                        and bounds.parallel
-                        and st.graph_key is not None
-                    ):
-                        # gang-formation rendezvous: park under the
-                        # (graph, algorithm) key; the first stager arms the
-                        # flush that decides fuse-vs-solo for everyone who
-                        # reached a boundary within the hold window
-                        # the rendezvous key carries the placed domain: a
-                        # gang's members share one grant and one interleaved
-                        # package table, so a gang must never straddle a
-                        # domain boundary (``None`` on single-domain runs —
-                        # the key degenerates to the old (graph, algorithm)).
-                        # With heterogeneous scan-sharing on, the key DROPS
-                        # the algorithm: every session on the same
-                        # (graph, domain) rendezvouses regardless of what it
-                        # computes — one topology pass, many compute bodies
-                        fkey = (
-                            st.graph_key,
-                            None if hetero else ex.desc.name,
-                            st.domain,
-                        )
-                        waiting = fusion_staged.setdefault(fkey, [])
-                        if not waiting:
-                            _push(t + fusing.hold_ns, EV_FUSE, fkey)
-                        waiting.append((st, bounds))
-                        continue
-                    _install_run(st, bounds)
+                        _place(st)
+                    with span("mq.decide", **_ids(rec)):
+                        bounds = self._decide(st.prep)
+                        if (
+                            fusing is not None
+                            and bounds.parallel
+                            and st.graph_key is not None
+                        ):
+                            # gang-formation rendezvous: park under the
+                            # (graph, algorithm) key; the first stager arms the
+                            # flush that decides fuse-vs-solo for everyone who
+                            # reached a boundary within the hold window
+                            # the rendezvous key carries the placed domain: a
+                            # gang's members share one grant and one interleaved
+                            # package table, so a gang must never straddle a
+                            # domain boundary (``None`` on single-domain runs —
+                            # the key degenerates to the old (graph, algorithm)).
+                            # With heterogeneous scan-sharing on, the key DROPS
+                            # the algorithm: every session on the same
+                            # (graph, domain) rendezvouses regardless of what it
+                            # computes — one topology pass, many compute bodies
+                            fkey = (
+                                st.graph_key,
+                                None if hetero else ex.desc.name,
+                                st.domain,
+                            )
+                            waiting = fusion_staged.setdefault(fkey, [])
+                            if not waiting:
+                                _push(t + fusing.hold_ns, EV_FUSE, fkey)
+                            waiting.append((st, bounds))
+                            continue
+                        _install_run(st, bounds)
 
-                step = st.srun.next_step()
+                with span("mq.decide", **_ids(st.record)):
+                    step = st.srun.next_step()
                 if step is None:
                     # all packages dispatched: release the grant right away —
                     # donated batches still executing on thieves run on the
@@ -2283,12 +2317,13 @@ class MultiQueryEngine:
                         trace = merge_member_trace(slot.trace, trace)
                         modeled += slot.modeled_ns
                         measured += slot.measured_ns
-                    self._account_iteration(
-                        st.executor, st.record, trace, modeled, measured
-                    )
-                    _sample(t)
-                    _push(t, EV_STEP, st)
-                    _wake_stalled(t)
+                    with span("mq.account", **_ids(st.record)):
+                        self._account_iteration(
+                            st.executor, st.record, trace, modeled, measured
+                        )
+                        _sample(t)
+                        _push(t, EV_STEP, st)
+                        _wake_stalled(t)
                     continue
 
                 if step.mode == "stalled":
@@ -2314,23 +2349,25 @@ class MultiQueryEngine:
                     step_ns += st.pending_migration_ns
                     st.pending_migration_ns = 0.0
                 step_measured = self._execute_step(
-                    st.executor, st.prep, step, step_ns, shard=_shard_for(st)
+                    st.executor, st.prep, step, step_ns, shard=_shard_for(st),
+                    record=st.record,
                 )
-                st.iter_measured_ns += step_measured
-                st.iter_modeled_ns += step_ns
-                # plain schedule steps (including post-preemption residual
-                # runs) carry (width, modeled, measured) — feed the table
-                self._observe_width(
-                    st.executor.desc.name,
-                    step.workers if step.mode == "parallel" else 1,
-                    step_ns,
-                    step_measured,
-                )
-                _sample(t)
-                _push(t + step_ns, EV_STEP, st)
-                # grant re-evaluation inside next_step may have released
-                # surplus workers (parallel rounding, early release)
-                _wake_stalled(t)
+                with span("mq.account", **_ids(st.record)):
+                    st.iter_measured_ns += step_measured
+                    st.iter_modeled_ns += step_ns
+                    # plain schedule steps (including post-preemption residual
+                    # runs) carry (width, modeled, measured) — feed the table
+                    self._observe_width(
+                        st.executor.desc.name,
+                        step.workers if step.mode == "parallel" else 1,
+                        step_ns,
+                        step_measured,
+                    )
+                    _sample(t)
+                    _push(t + step_ns, EV_STEP, st)
+                    # grant re-evaluation inside next_step may have released
+                    # surplus workers (parallel rounding, early release)
+                    _wake_stalled(t)
 
             if stalled:
                 raise RuntimeError(
