@@ -126,7 +126,7 @@ def build_graph(scale: int):
 
 
 def print_staged_tables(backend: PallasBackend) -> None:
-    """The dst-tiled tables the backend staged on the device."""
+    """The row-split dst-tiled tables the backend staged on the device."""
     for direction, shape, nbytes in backend.staged_tables():
         print(f"tile tables {direction}: shape={shape} bytes={nbytes}")
 

@@ -226,21 +226,19 @@ class _PallasHandle:
     """Device state one :class:`PallasBackend` plan executes against."""
 
     kind: str                      # "pr_pull" | "bfs" | "degree_count" | "inline"
-    src_chunks: Any = None         # [T, C] dst-tiled COO (spmv kinds)
-    dstl_chunks: Any = None        # [T, C]
+    table: Any = None              # row-split dst-tiled COO (spmv kinds): a TileTable
     dst_tile: int = 0
     num_vertices: int = 0
     edge_prefix: np.ndarray | None = None  # [V+1] in-edges with dst < v (pr_pull)
     ids_pad: Any = None            # [2, E] endpoint ids mod C (degree_count)
     # shard-local dispatch (locality domains): the plan's shard covers dst
-    # tiles [tile_lo, tile_hi) and shard_src/shard_dstl hold that slab —
-    # ranges inside it dispatch against the slab (what a domain's device
-    # would actually hold), anything outside falls back to the full tables
-    # so results stay exact when a frontier drifts off its placed shard
+    # tiles [tile_lo, tile_hi) and shard_table holds that slab — ranges
+    # inside it dispatch against the slab (what a domain's device would
+    # actually hold), anything outside falls back to the full table so
+    # results stay exact when a frontier drifts off its placed shard
     tile_lo: int = 0
     tile_hi: int = 0
-    shard_src: Any = None
-    shard_dstl: Any = None
+    shard_table: Any = None
 
 
 class PallasBackend:
@@ -250,11 +248,11 @@ class PallasBackend:
     the padding/unpadding contract):
 
     * ``pagerank_pull`` — a package batch is a contiguous range of *target*
-      vertices; the dst-tiled COO built by ``kernels/spmv/ops.build_tiles``
-      is sliced to the tiles covering the range, the SpMV kernel aggregates
-      each tile on the MXU-shaped one-hot path, and lanes outside the range
-      are masked off before the partial is applied to the executor's
-      accumulator.
+      vertices; the row-split dst-tiled COO built by
+      ``kernels/spmv/ops.build_tiles`` is sliced to the rows of the tiles
+      covering the range, the SpMV kernel aggregates each row into its tile
+      on the one-hot path, and lanes outside the range are masked off before
+      the partial is applied to the executor's accumulator.
     * ``bfs_top_down`` — frontier expansion *is* an SpMV over the boolean
       semiring: contributions are the indicator of the batch's frontier
       slots, the kernel counts per-target frontier parents over the
@@ -310,31 +308,27 @@ class PallasBackend:
         return None
 
     # ------------------------------------------------------------ staging
-    def _spmv_tables(
+    def _spmv_table(
         self, key: tuple, src: np.ndarray, dst: np.ndarray, num_vertices: int
-    ) -> tuple[Any, Any, int]:
-        """dst-tiled COO tables for one edge list, cached per graph+kind."""
+    ) -> tuple[Any, int]:
+        """Row-split dst-tiled table for one edge list, cached per graph+kind."""
         cached = self._graph_tables.get(key)
         if cached is not None:
-            return cached.src_chunks, cached.dstl_chunks, cached.dst_tile
+            return cached.table, cached.dst_tile
         from ..kernels.spmv.ops import build_tiles
         from ..kernels.spmv.spmv import DST_TILE
 
-        src_chunks, dstl_chunks, _ = build_tiles(src, dst, num_vertices)
-        self._graph_tables[key] = _PallasHandle(
-            kind="tables",
-            src_chunks=src_chunks,
-            dstl_chunks=dstl_chunks,
-            dst_tile=DST_TILE,
-        )
-        return src_chunks, dstl_chunks, DST_TILE
+        table = build_tiles(src, dst, num_vertices)
+        self._graph_tables[key] = _PallasHandle(kind="tables", table=table, dst_tile=DST_TILE)
+        return table, DST_TILE
 
     def staged_tables(self) -> list[tuple[str, tuple[int, ...], int]]:
-        """``(direction, shape, bytes)`` of every staged dst-tiled table
-        pair: ``"in"`` serves PR-pull, ``"out"`` BFS; bytes count both the
-        source and the local-target table."""
+        """``(direction, shape, bytes)`` of every staged row-split table:
+        ``"in"`` serves PR-pull, ``"out"`` BFS; the shape is the source
+        table's ``[rows, SUB_CHUNK]``, and bytes count the source and
+        local-target tables and the row → tile map."""
         return [
-            (key[-1], tuple(h.src_chunks.shape), h.src_chunks.nbytes + h.dstl_chunks.nbytes)
+            (key[-1], tuple(h.table.src.shape), h.table.nbytes)
             for key, h in self._graph_tables.items()
             if h.kind == "tables"
         ]
@@ -377,17 +371,14 @@ class PallasBackend:
         if kind == "pr_pull":
             in_src, in_dst = executor.pull_edges()
             nv = int(executor.graph.num_vertices)
-            src_chunks, dstl_chunks, tile = self._spmv_tables(
-                (gkey, "in"), in_src, in_dst, nv
-            )
+            table, tile = self._spmv_table((gkey, "in"), in_src, in_dst, nv)
             # in-edge list is sorted by target: a prefix sum of in-degrees
             # gives exact per-range edge counts without touching the device
             in_deg = np.bincount(in_dst, minlength=nv)
             prefix = np.concatenate([[0], np.cumsum(in_deg)])
             handle = _PallasHandle(
                 kind="pr_pull",
-                src_chunks=src_chunks,
-                dstl_chunks=dstl_chunks,
+                table=table,
                 dst_tile=tile,
                 num_vertices=nv,
                 edge_prefix=prefix,
@@ -397,19 +388,15 @@ class PallasBackend:
                 # [tile_lo, tile_hi); the slab is the shard-local device state
                 handle.tile_lo = int(shard.v_lo) // tile
                 handle.tile_hi = -(-int(shard.v_hi) // tile)
-                handle.shard_src = src_chunks[handle.tile_lo : handle.tile_hi]
-                handle.shard_dstl = dstl_chunks[handle.tile_lo : handle.tile_hi]
+                handle.shard_table = table.slab(handle.tile_lo, handle.tile_hi)
             self._warm_spmv(handle)
         elif kind == "bfs":
             src, dst = executor.out_edges()
             nv = int(executor.graph.num_vertices)
-            src_chunks, dstl_chunks, tile = self._spmv_tables(
-                (gkey, "out"), src, dst, nv
-            )
+            table, tile = self._spmv_table((gkey, "out"), src, dst, nv)
             handle = _PallasHandle(
                 kind="bfs",
-                src_chunks=src_chunks,
-                dstl_chunks=dstl_chunks,
+                table=table,
                 dst_tile=tile,
                 num_vertices=nv,
             )
@@ -461,15 +448,15 @@ class PallasBackend:
         bounds = np.linspace(t0, t1, w + 1).round().astype(int)
         return [(int(a), int(b)) for a, b in zip(bounds[:-1], bounds[1:]) if b > a]
 
-    def _tile_slab(self, handle: _PallasHandle, a: int, b: int) -> tuple[Any, Any, int]:
-        """Device chunk tables holding absolute dst tiles [a, b), and the
-        row of tile ``a`` in them: the shard-local slab when the range lies
-        inside the plan's shard (the common case under locality placement —
-        the dispatch never touches other shards' tables), the full tables
-        otherwise (a drifted frontier stays exact)."""
-        if handle.shard_src is not None and a >= handle.tile_lo and b <= handle.tile_hi:
-            return handle.shard_src, handle.shard_dstl, a - handle.tile_lo
-        return handle.src_chunks, handle.dstl_chunks, a
+    def _tile_slab(self, handle: _PallasHandle, a: int, b: int) -> Any:
+        """The device table holding absolute dst tiles [a, b): the
+        shard-local slab when the range lies inside the plan's shard (the
+        common case under locality placement — the dispatch never touches
+        other shards' tables), the full table otherwise (a drifted frontier
+        stays exact)."""
+        if handle.shard_table is not None and a >= handle.tile_lo and b <= handle.tile_hi:
+            return handle.shard_table
+        return handle.table
 
     def _spmv_range(
         self, handle: _PallasHandle, contrib, t0: int, t1: int, workers: int,
@@ -478,20 +465,27 @@ class PallasBackend:
         """Aggregate dst tiles [t0, t1) at gang width ``workers``; returns
         the [V] per-target sums, zero outside targets [lo, hi) (unpadding).
 
-        Window starts and bounds are traced, so each distinct slice length
-        compiles once, however many ranges it serves."""
+        Window starts and bounds are traced and a window's row count follows
+        its length (``TileTable.window_rows``), so each distinct slice
+        length compiles once, however many ranges it serves. A launch's
+        span carries the slots it gathers and the edges its tiles hold."""
         import jax.numpy as jnp
 
         from ..kernels.spmv.ops import spmv_window
+        from ..kernels.spmv.spmv import SUB_CHUNK
 
         tile = handle.dst_tile
-        out = jnp.zeros((handle.src_chunks.shape[0] * tile,), jnp.float32)
+        out = jnp.zeros((handle.table.n_tiles * tile,), jnp.float32)
         for a, b in self._grid_slices(t0, t1, workers):
-            src_chunks, dstl_chunks, row = self._tile_slab(handle, a, b)
-            with span("mq.launch"):
+            table = self._tile_slab(handle, a, b)
+            i, j = a - table.first_tile, b - table.first_tile
+            rows = table.window_rows(b - a)
+            edges = int(table.tile_edge_start[j] - table.tile_edge_start[i])
+            with span("mq.launch", slots=rows * SUB_CHUNK, edges=edges):
                 out = spmv_window(
-                    out, src_chunks, dstl_chunks, contrib, row, a * tile, lo, hi,
-                    n_tiles=b - a, dst_tile=tile, interpret=self.interpret,
+                    out, table.src, table.dstl, contrib, int(table.tile_row_start[i]),
+                    a * tile, lo, hi, row_tile=table.row_tile, n_tiles=b - a,
+                    n_rows=rows, dst_tile=tile, interpret=self.interpret,
                 )
         return out[: handle.num_vertices]
 
@@ -524,7 +518,7 @@ class PallasBackend:
 
         h = plan.handle
         ex = plan.executor
-        n_tiles = h.src_chunks.shape[0]
+        n_tiles = h.table.n_tiles
         for lo, hi in self._ranges(plan, step):
             # the frontier indicator is built on the host: a device scatter
             # would compile once per distinct member count

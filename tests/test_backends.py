@@ -219,9 +219,48 @@ def test_pallas_staged_tables_report_what_is_on_the_device(pallas_graph):
     _run_one(eng, BFSExecutor(pallas_graph, 0))
     staged = backend.staged_tables()
     assert sorted(d for d, _, _ in staged) == ["in", "out"]
-    for _, (tiles, chunk), nbytes in staged:
-        assert tiles * 512 >= pallas_graph.num_vertices
-        assert nbytes == 2 * tiles * chunk * 4
+    e, n_tiles = pallas_graph.num_edges, -(-pallas_graph.num_vertices // 512)
+    for _, (rows, width), nbytes in staged:
+        # rows of 512 slots: every edge, at most 511 padding slots per tile,
+        # then up to 7 padding rows; bytes: two [rows, 512] int32 tables and
+        # the [rows] int32 row -> tile map
+        assert width == 512 and rows % 8 == 0
+        assert e <= rows * width <= e + n_tiles * 511 + 7 * 512
+        assert nbytes == 2 * rows * width * 4 + rows * 4
+
+
+def test_pallas_shard_slab_matches_the_full_table(medium_rmat):
+    """Under locality domains a PR-pull plan stages its shard's rows of the
+    row-split table; a range inside the shard dispatches against that slab
+    and gives the full table's sums at every gang width."""
+    import jax.numpy as jnp
+
+    from repro.graph.partition import GraphPartition
+    from repro.kernels.spmv import spmv_ref
+
+    backend = PallasBackend(interpret=True)
+    ex = PageRankExecutor(medium_rmat, mode="pull", max_iters=2, tol=0)
+    ex.start()
+    shard = GraphPartition.build(medium_rmat, 2).shards[1]
+    local = backend.prepare(ex, object(), shard).handle
+    full = backend.prepare(ex, object()).handle
+    a, b = local.tile_lo, local.tile_hi
+    assert 0 < a < b == full.table.n_tiles
+    assert local.shard_table.src.shape[0] < full.table.src.shape[0]
+    assert backend._tile_slab(local, a, b) is local.shard_table
+    assert backend._tile_slab(full, a, b) is full.table
+
+    nv = medium_rmat.num_vertices
+    contrib = jnp.asarray(np.random.default_rng(5).normal(size=nv).astype(np.float32))
+    src, dst = ex.pull_edges()
+    ref = np.asarray(spmv_ref(jnp.asarray(src), jnp.asarray(dst), contrib, nv))
+    lo, hi = int(shard.v_lo), int(shard.v_hi)
+    for workers in (1, 3):
+        got = np.asarray(backend._spmv_range(local, contrib, a, b, workers, lo, hi))
+        want = np.asarray(backend._spmv_range(full, contrib, a, b, workers, lo, hi))
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_allclose(got[lo:hi], ref[lo:hi], rtol=1e-5, atol=1e-5)
+        assert not got[:lo].any() and not got[hi:].any()
 
 
 def test_pallas_interpret_mode_follows_the_platform(monkeypatch):

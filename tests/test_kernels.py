@@ -9,6 +9,8 @@ from repro.kernels.degree_count import degree_count, degree_count_ref
 from repro.kernels.embedding_bag import embedding_bag, embedding_bag_ref
 from repro.kernels.scoring import score_topk, scoring_pallas, scoring_ref, topk_ref
 from repro.kernels.spmv import build_tiles, spmv, spmv_ref
+from repro.kernels.spmv.ops import spmv_window
+from repro.kernels.spmv.spmv import DST_TILE, SUB_CHUNK
 
 
 # ---------------- degree count ----------------
@@ -41,8 +43,8 @@ def test_spmv_shapes(v, e, dtype, rng):
     src = rng.integers(0, v, e)
     dst = rng.integers(0, v, e)
     contrib = jnp.asarray(rng.normal(size=v).astype(dtype))
-    sc, dc, _ = build_tiles(src, dst, v)
-    out = spmv(sc, dc, contrib, v, interpret=True)
+    t = build_tiles(src, dst, v)
+    out = spmv(t.src, t.dstl, t.row_tile, contrib, v, interpret=True)
     ref = spmv_ref(jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32), contrib, v)
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
 
@@ -52,10 +54,88 @@ def test_spmv_empty_rows(rng):
     src = rng.integers(0, v, 100)
     dst = np.full(100, 3)  # everything lands on one vertex
     contrib = jnp.ones(v, jnp.float32)
-    sc, dc, _ = build_tiles(src, dst, v)
-    out = spmv(sc, dc, contrib, v, interpret=True)
+    t = build_tiles(src, dst, v)
+    out = spmv(t.src, t.dstl, t.row_tile, contrib, v, interpret=True)
     assert float(out[3]) == pytest.approx(100.0)
     assert float(out.sum()) == pytest.approx(100.0)
+
+
+def _row_graph(case, rng):
+    """``(src, dst, V)`` with the tile sizes a row-split case needs: 6
+    tiles of 512 targets (130 for "wide"), 5,000 random edges, and per
+    case a hub tile whose edges fill several rows or an empty tile."""
+    v = (130 if case == "wide" else 6) * DST_TILE
+    src = rng.integers(0, v, 5000)
+    dst = rng.integers(0, v, 5000)
+    if case in ("hub", "wide"):
+        # 3,000 more edges: 6+ rows against 1-2 elsewhere, in tile 2 of 6
+        # or in the last of 130, past a 128-tile window from tile 0
+        hub = 2 if case == "hub" else 129
+        src = np.concatenate([src, rng.integers(0, v, 3000)])
+        dst = np.concatenate([dst, rng.integers(hub * DST_TILE, (hub + 1) * DST_TILE, 3000)])
+    elif case == "empty-tile":
+        keep = dst // DST_TILE != 3
+        src, dst = src[keep], dst[keep]
+    return src, dst, v
+
+
+@pytest.mark.parametrize(
+    "case,a,n_tiles,clamped",
+    [
+        ("hub", 2, 1, False),          # the hub tile alone, split over several rows
+        ("hub", 1, 3, False),          # windows at offsets and lengths around it,
+        ("hub", 0, 6, False),          # reading rows past their last tile
+        ("hub", 5, 1, False),
+        ("hub", 3, 2, True),           # rows past the table's end: the start clamps
+        ("hub", 4, 2, True),
+        ("empty-tile", 3, 1, False),   # a tile with no edges has one empty row
+        ("empty-tile", 2, 3, False),
+        ("wide", 0, 128, False),       # rows past the window's 128 output lanes
+    ],
+)
+def test_spmv_window_row_table(case, a, n_tiles, clamped, rng):
+    src, dst, v = _row_graph(case, rng)
+    t = build_tiles(src, dst, v)
+    contrib = jnp.asarray(rng.normal(size=v).astype(np.float32))
+    ref = np.asarray(spmv_ref(jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32), contrib, v))
+    rows = t.window_rows(n_tiles)
+    row = int(t.tile_row_start[a])
+    assert row + rows >= int(t.tile_row_start[a + n_tiles])
+    assert (row + rows > t.src.shape[0]) == clamped
+    lo, hi = a * DST_TILE + 5, (a + n_tiles) * DST_TILE - 7
+    out = spmv_window(
+        jnp.full((v,), 9.0, jnp.float32), t.src, t.dstl, contrib, row, a * DST_TILE, lo, hi,
+        row_tile=t.row_tile, n_tiles=n_tiles, n_rows=rows, interpret=True,
+    )
+    want = np.full(v, 9.0, np.float32)
+    want[a * DST_TILE : (a + n_tiles) * DST_TILE] = 0.0
+    want[lo:hi] = ref[lo:hi]
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("case", ["hub", "empty-tile", "one-vertex"])
+def test_row_table_slots_are_bounded(case, rng):
+    """Rows never hold more than ``E + T * (SUB_CHUNK - 1)`` slots, each tile
+    at least one row, and every edge sits in its tile's rows."""
+    if case == "one-vertex":
+        src, dst, v = rng.integers(0, 600, 2000), np.full(2000, 3), 600
+    else:
+        src, dst, v = _row_graph(case, rng)
+    t = build_tiles(src, dst, v)
+    trs = t.tile_row_start
+    slots = int(trs[-1]) * SUB_CHUNK
+    assert slots <= len(src) + t.n_tiles * (SUB_CHUNK - 1)
+    assert (np.diff(trs) >= 1).all() and t.src.shape[0] % 8 == 0
+    edges = np.bincount(np.asarray(dst) // DST_TILE, minlength=t.n_tiles)
+    assert np.array_equal(np.diff(t.tile_edge_start), edges)
+    row_tile = np.asarray(t.row_tile)
+    assert np.array_equal(row_tile[: trs[-1]], np.repeat(np.arange(t.n_tiles), np.diff(trs)))
+    assert (row_tile[trs[-1] :] == t.n_tiles).all()
+    dstl = np.asarray(t.dstl)
+    assert int((dstl >= 0).sum()) == len(src)
+    for tile in range(t.n_tiles):
+        block = dstl[trs[tile] : trs[tile + 1]]
+        assert int((block >= 0).sum()) == edges[tile]
 
 
 # ---------------- scoring ----------------

@@ -4,9 +4,12 @@ Each test lowers a kernel for one chip of a described ``v5e:2x2`` topology
 and compiles it with the TPU compiler; no chip is attached, nothing runs.
 That catches what the Pallas interpreter never checks: block shapes that
 break the (8, 128) tiling rule, in-kernel ops Mosaic cannot lower, and
-blocks that overflow VMEM. Shapes: the scale-20 RMAT graph ``chip_smoke.py``
-runs (2,048 dst tiles; its largest tile holds 82,982 edges), a ragged slice
-as the backend cuts them, and a small graph.
+blocks that overflow VMEM, and more scalar prefetch than SMEM holds.
+Shapes: the row-split table of the benchmark's undirected Graph500 SCALE-20
+graph (2,048 dst tiles in 66,561 rows of 512 slots, padded to 66,568; a
+512-tile window reads at most 16,987 rows, one tile at most 308, each
+rounded up to 16,992 and 312), a ragged slice as the backend cuts them,
+and a small graph.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU runtime, and every test worker imports
@@ -21,15 +24,16 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.degree_count.degree_count import EDGE_BLOCK, degree_count_pallas
 from repro.kernels.spmv.ops import spmv_window
-from repro.kernels.spmv.spmv import DST_TILE, SUB_CHUNK, spmv_pallas
+from repro.kernels.spmv.spmv import DST_TILE, ROW_BLOCK, SUB_CHUNK, spmv_pallas
 
 SCALE20_V = 1 << 20
-SCALE20_MAX_TILE_EDGES = 82_982
+SCALE20_ROWS = 66_561
+SCALE20_WINDOW_ROWS = {1: 308, 512: 16_987}
 
 
-def _chunk(max_tile_edges: int) -> int:
-    """CHUNK as ``build_tiles`` pads it."""
-    return -(-max_tile_edges // SUB_CHUNK) * SUB_CHUNK
+def _rows(rows: int) -> int:
+    """Rows as ``build_tiles`` and ``TileTable.window_rows`` round them."""
+    return -(-rows // ROW_BLOCK) * ROW_BLOCK
 
 
 @pytest.fixture(scope="module")
@@ -69,20 +73,21 @@ def _compile(fn, one_chip, *shapes):
 
 
 @pytest.mark.parametrize(
-    "n_tiles,chunk,num_vertices",
+    "n_tiles,rows,num_vertices",
     [
-        (SCALE20_V // DST_TILE, _chunk(SCALE20_MAX_TILE_EDGES), SCALE20_V),
-        (13, _chunk(700), 13 * DST_TILE),
-        (2, _chunk(100), 1024),
+        (SCALE20_V // DST_TILE, _rows(SCALE20_ROWS), SCALE20_V),   # the whole table
+        (13, 32, 13 * DST_TILE),
+        (2, 8, 1024),
     ],
     ids=["scale20", "ragged-slice", "small"],
 )
-def test_spmv_compiles_for_v5e(one_chip, no_persistent_cache, n_tiles, chunk, num_vertices):
+def test_spmv_compiles_for_v5e(one_chip, no_persistent_cache, n_tiles, rows, num_vertices):
     compiled = _compile(
-        functools.partial(spmv_pallas, interpret=False),
+        functools.partial(spmv_pallas, n_tiles=n_tiles, interpret=False),
         one_chip,
-        ((n_tiles, chunk), jnp.int32),
-        ((n_tiles, chunk), jnp.int32),
+        ((rows, SUB_CHUNK), jnp.int32),
+        ((rows, SUB_CHUNK), jnp.int32),
+        ((rows,), jnp.int32),
         ((num_vertices,), jnp.float32),
     )
     assert "tpu_custom_call" in compiled.as_text()
@@ -90,20 +95,27 @@ def test_spmv_compiles_for_v5e(one_chip, no_persistent_cache, n_tiles, chunk, nu
     assert out.shape == (n_tiles, DST_TILE) and out.dtype == jnp.float32
 
 
-@pytest.mark.parametrize("n_tiles", [2, 512])
+@pytest.mark.parametrize("n_tiles", [1, 512])
 def test_spmv_window_compiles_for_v5e(one_chip, no_persistent_cache, n_tiles):
     """The windowed call PallasBackend dispatches, over the whole scale-20
-    tables: a slice from a traced row, the kernel, the masked write-back."""
-    t, chunk = SCALE20_V // DST_TILE, _chunk(SCALE20_MAX_TILE_EDGES)
+    table: row slices from a traced row, the row → tile map as scalar
+    prefetch, the kernel, the masked write-back."""
+    rows = _rows(SCALE20_ROWS)
     scalar = ((), jnp.int32)
+    window = functools.partial(
+        spmv_window, n_tiles=n_tiles, n_rows=_rows(SCALE20_WINDOW_ROWS[n_tiles]), interpret=False
+    )
     compiled = _compile(
-        functools.partial(spmv_window, n_tiles=n_tiles, interpret=False),
+        lambda out, src, dstl, contrib, row, base, lo, hi, row_tile: window(
+            out, src, dstl, contrib, row, base, lo, hi, row_tile=row_tile
+        ),
         one_chip,
         ((SCALE20_V,), jnp.float32),
-        ((t, chunk), jnp.int32),
-        ((t, chunk), jnp.int32),
+        ((rows, SUB_CHUNK), jnp.int32),
+        ((rows, SUB_CHUNK), jnp.int32),
         ((SCALE20_V,), jnp.float32),
         scalar, scalar, scalar, scalar,
+        ((rows,), jnp.int32),
     )
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.out_info.shape == (SCALE20_V,)

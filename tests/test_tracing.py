@@ -101,6 +101,19 @@ def test_spans_name_their_session_and_query(traced):
     assert {(a["session"], a["query"]) for n, a in named if n == "mq.query_start"} == {(0, 0), (1, 0), (2, 0)}
 
 
+def test_spmv_launches_carry_their_slots_and_edges(graph, traced):
+    """Every SpMV launch says how many table slots it gathers and how many
+    edges its tiles hold; degree-count launches gather none."""
+    *_, tr = traced
+    launches = [a for (n, _, _), a in zip(tr.spans, tr.args) if n == "mq.launch"]
+    spmv = [a for a in launches if "slots" in a]
+    assert spmv and len(spmv) < len(launches)
+    for a in spmv:
+        assert a["slots"] % 512 == 0 and 0 <= a["edges"] <= a["slots"]
+    assert sum(a["edges"] for a in spmv) > 0
+    assert trace_spans.reduce(tr)["metrics"]["gather_slots_per_edge"] >= 1
+
+
 def test_tracing_changes_no_decision(graph, traced):
     """The same run with the profiler off gives the same report: edges,
     iterations, modeled times, decision traces and answers."""
@@ -186,6 +199,24 @@ def test_a_span_that_outlives_its_parent_is_cut_at_the_parent_end():
         (5, 10, ("mq.dispatch", "mq.sync")),
         (12, 14, ("mq.account",)),
     ]
+
+
+@pytest.mark.parametrize(
+    "launches, expect",
+    [
+        ([(10, {"slots": 1024, "edges": 1000}), (60, {"slots": 512, "edges": 200})], 1536 / 1200),
+        ([(10, {"slots": 1024, "edges": 1000}), (160, {"slots": 512, "edges": 1})], 1024 / 1000),
+        ([(10, {}), (20, {"slots": 512, "edges": 512})], 1.0),   # a degree-count launch
+        ([(10, {})], None),
+    ],
+    ids=["two-launches", "one-past-the-window", "no-args", "nothing-gathered"],
+)
+def test_gather_slots_per_edge(launches, expect):
+    spans = [("mq.dispatch", 0, 150)] + [("mq.launch", s, s + 5) for s, _ in launches]
+    args = [{}] + [a for _, a in launches]
+    tr = trace_spans.Trace(spans, [("execute:bfs", 0, 150)], [(0, 150)], args)
+    got = trace_spans.reduce(tr)["metrics"]["gather_slots_per_edge"]
+    assert got == (None if expect is None else pytest.approx(expect))
 
 
 @pytest.mark.parametrize(

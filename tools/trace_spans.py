@@ -14,7 +14,10 @@ the device's programs and reduces them:
   (self time of ``mq.prepare``, ``mq.decide`` and ``mq.account`` per
   step), ``syncs_per_step`` (``mq.sync`` per step), ``dispatch_idle_share``
   (device-idle time under ``mq.dispatch`` or the backend spans inside it,
-  over the window, %), beside ``device_idle_share`` and ``spans_per_step``.
+  over the window, %), beside ``device_idle_share`` and ``spans_per_step``;
+* ``gather_slots_per_edge``: the table slots the SpMV launches gathered
+  over the edges their tiles hold (the ``slots``/``edges`` args of each
+  ``mq.launch`` that starts in the window): 1 where no slot is padding.
 
 The window is the benchmark's: from the first to the last harness span
 (``engine``, ``execute:<kind>``), or the program spans' extent in a trace
@@ -180,6 +183,12 @@ def reduce(tr: Trace) -> dict | None:
     def per_step(x):
         return x / steps if steps else None
 
+    slots = gathered = 0
+    for (name, s, _), a in zip(tr.spans, tr.args):
+        if name == "mq.launch" and w0 <= s < w1 and "slots" in a:
+            slots += a["slots"]
+            gathered += a["edges"]
+
     starts = count.get("mq.query_start", 0)
     sched = sum(self_ns.get(n, 0.0) for n in SCHEDULE)
     return {
@@ -192,6 +201,7 @@ def reduce(tr: Trace) -> dict | None:
             "dispatch_idle_share": 100.0 * under_dispatch / window if window else None,
             "device_idle_share": 100.0 * idle_ns / window if window else None,
             "spans_per_step": per_step(sum(count.values())),
+            "gather_slots_per_edge": slots / gathered if gathered else None,
         },
         "program": {
             n: {"count": count.get(n, 0), "total_s": total.get(n, 0.0) / 1e9, "self_s": self_ns.get(n, 0.0) / 1e9}
