@@ -29,20 +29,21 @@ def main(argv: list[str] | None = None) -> int:
 
     import jax
 
-    from harness import reference
+    from harness import graphs, reference
     from harness.control import control_errors
-    from harness.kronecker import kronecker_edges, vertex_map
     from harness.traffic import QueryStream
 
     with open(BENCH_DIR / "configs" / f"{args.config}.json") as f:
         config = json.load(f)
+    generator = graphs.load(config)
     iters = int(config["queries"]["pagerank_pull"]["iterations"])
     mix = {"sessions": args.queries, "mix": {"pagerank_pull": args.queries}}
     readings = {}
     for seed in args.seeds:
-        src, dst = kronecker_edges(config, seed)
-        g = reference.Graph(src, dst, 1 << int(config["SCALE"]))
-        dampings = [q.param for q in QueryStream(mix, config, g.out_deg, vertex_map(config, seed), seed, stream=0).batch()]
+        src, dst = generator.edges(config, seed)
+        g = reference.Graph(src, dst, generator.num_vertices(config))
+        vmap = generator.vertex_map(config, seed)
+        dampings = [q.param for q in QueryStream(mix, config, g.out_deg, vmap, seed, stream=0).batch()]
         readings[seed] = control_errors(g, dampings, iters)
     least = min(min(v) for v in readings.values())
     print(json.dumps({

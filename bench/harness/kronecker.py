@@ -1,4 +1,5 @@
-"""The Graph500 Kronecker edge generator, made on the device from a seed.
+"""The Graph500 Kronecker edge generator, made on the device from a seed:
+the generator of every configuration that names none (``harness.graphs``).
 
 A copy of the specification's reference generator (Graph500 specification,
 section "Kronecker generator"): every edge descends SCALE levels of the
@@ -99,3 +100,15 @@ def kronecker_edges(cfg: dict, seed: int) -> tuple[np.ndarray, np.ndarray]:
         )
     )
     return ij[0], ij[1]
+
+
+edges = kronecker_edges  # the name ``harness.graphs`` asks a generator for
+
+
+def num_vertices(cfg: dict) -> int:
+    return 1 << int(cfg["SCALE"])
+
+
+def small(cfg: dict, k: int) -> dict:
+    """The configuration at ``2**k`` vertices."""
+    return dict(cfg, SCALE=k)

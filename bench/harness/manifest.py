@@ -1,11 +1,13 @@
 """``BENCHMARK.json`` and the files it names, found by name.
 
 A cell names a configuration and a traffic mix. The configuration's file is
-the one its ``BENCHMARK.json`` entry gives; a traffic mix is
-``bench/traffic/<traffic>.json``; every metric is read by
-``bench/metrics/<metric name>.py``, whose ``read(run)`` returns a number or
-``None`` when it finds nothing to read. A new configuration, mix or metric
-is new files and a new entry, never an edit of the harness.
+the one its ``BENCHMARK.json`` entry gives, and its graph generator is
+``bench/generators/<generator>.py`` where it names one
+(``harness.graphs``); a traffic mix is ``bench/traffic/<traffic>.json``;
+every metric is read by ``bench/metrics/<metric name>.py``, whose
+``read(run)`` returns a number or ``None`` when it finds nothing to read. A
+new configuration, generator, mix or metric is new files and a new entry,
+never an edit of the harness.
 """
 from __future__ import annotations
 
@@ -79,16 +81,21 @@ def load_cell(root: Path, workload: str, bench_dir: Path = BENCH_DIR) -> Cell:
     )
 
 
-def metric_reader(name: str, bench_dir: Path = BENCH_DIR) -> ModuleType:
-    """The module ``bench/metrics/<name>.py``, loaded by path (a metric name
+def load_module(directory: str, name: str, bench_dir: Path = BENCH_DIR) -> ModuleType:
+    """The module ``bench/<directory>/<name>.py``, loaded by path (a name
     may hold dots)."""
-    path = bench_dir / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    if spec is None or spec.loader is None:
-        raise FileNotFoundError(f"no reader for metric {name!r} at {path}")
+    path = Path(bench_dir) / directory / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(f"bench_{directory}_{name}", path)
+    if spec is None or spec.loader is None or not path.is_file():
+        raise FileNotFoundError(f"no {directory} module {name!r} at {path}")
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def metric_reader(name: str, bench_dir: Path = BENCH_DIR) -> ModuleType:
+    """The reader of metric ``name``: ``bench/metrics/<name>.py``."""
+    return load_module("metrics", name, bench_dir)
 
 
 def read_metrics(metrics: list[Metric], run: Any, bench_dir: Path = BENCH_DIR) -> dict:

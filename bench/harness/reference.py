@@ -1,7 +1,8 @@
 """Plain references and the comparison that decides ``correct``.
 
 Straightforward numpy over the benchmark's own edge list, importing nothing
-of the program: BFS levels, PageRank power iteration in float64 (dangling
+of the program: BFS levels (over a CSR of that list, in time proportional
+to the edges reached), PageRank power iteration in float64 (dangling
 rank spread uniformly, each query's own damping), degree counts mod the
 counter array. Each also gives the work count the engine's reported edges
 are held to.
@@ -25,10 +26,21 @@ class Graph:
         self.src = np.asarray(self.src, np.int64)
         self.dst = np.asarray(self.dst, np.int64)
         self.out_deg = np.bincount(self.src, minlength=self.num_vertices)
+        self._csr: tuple[np.ndarray, np.ndarray] | None = None
 
     @property
     def num_edges(self) -> int:
         return int(self.src.size)
+
+    def out_csr(self) -> tuple[np.ndarray, np.ndarray]:
+        """``(offsets, targets)``: the out-neighbours of ``v`` are
+        ``targets[offsets[v]:offsets[v + 1]]``, in edge-list order. Built
+        on first use."""
+        if self._csr is None:
+            offsets = np.zeros(self.num_vertices + 1, np.int64)
+            np.cumsum(self.out_deg, out=offsets[1:])
+            self._csr = (offsets, self.dst[np.argsort(self.src, kind="stable")])
+        return self._csr
 
 
 def pagerank(g: Graph, damping: float, iters: int, dtype=np.float64) -> np.ndarray:
@@ -46,17 +58,21 @@ def pagerank(g: Graph, damping: float, iters: int, dtype=np.float64) -> np.ndarr
 
 
 def bfs_levels(g: Graph, root: int) -> np.ndarray:
-    """Level-synchronous top-down BFS; -1 for unreached vertices."""
+    """Level-synchronous top-down BFS over the out-CSR, each level reading
+    only its frontier's edges; -1 for unreached vertices."""
+    offsets, targets = g.out_csr()
     level = np.full(g.num_vertices, -1, np.int32)
     level[root] = 0
-    frontier = np.zeros(g.num_vertices, bool)
-    frontier[root] = True
+    frontier = np.array([root], np.int64)
     depth = 0
-    while frontier.any():
+    while frontier.size:
         depth += 1
-        touched = np.zeros(g.num_vertices, bool)
-        touched[g.dst[frontier[g.src]]] = True
-        new = touched & (level < 0)
+        starts = offsets[frontier]
+        counts = offsets[frontier + 1] - starts
+        # the frontier's edges: starts[i] .. starts[i] + counts[i] - 1, in a row
+        first = np.cumsum(counts) - counts
+        nbrs = targets[np.repeat(starts - first, counts) + np.arange(int(counts.sum()))]
+        new = np.unique(nbrs[level[nbrs] < 0])
         level[new] = depth
         frontier = new
     return level

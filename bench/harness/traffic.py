@@ -23,7 +23,7 @@ class QueryStream:
         self, traffic: dict, config: dict, degree: np.ndarray, vertex_map: np.ndarray, seed: int, stream: int
     ):
         """``degree`` is over the run's vertex ids; ``vertex_map[v]`` is the
-        run's id of structure vertex ``v`` (``kronecker.vertex_map``)."""
+        run's id of structure vertex ``v`` (the generator's ``vertex_map``)."""
         self.kinds = [k for k, n in traffic["mix"].items() for _ in range(int(n))]
         unknown = set(self.kinds) - set(KINDS)
         if unknown:

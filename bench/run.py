@@ -3,8 +3,10 @@
 
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The cell (``BENCHMARK.json``) names a configuration, a graph made by the
-Graph500 Kronecker generator, and a traffic mix of closed-loop batches: one
+The cell (``BENCHMARK.json``) names a configuration, whose graph its
+generator makes (``harness.graphs``: the Graph500 Kronecker generator unless
+the configuration names one in ``bench/generators``), and a traffic mix of
+closed-loop batches: one
 ``MultiQueryEngine.run_sessions`` call of ``sessions`` x 1 query through the
 program's ``PallasBackend``, the next batch as soon as the last returns.
 
@@ -232,7 +234,15 @@ def passes(checks: dict) -> bool:
     )
 
 
-def main(argv: list[str] | None = None, *, root: Path = ROOT, platforms: tuple[str, ...] = PLATFORMS) -> int:
+def main(
+    argv: list[str] | None = None,
+    *,
+    root: Path = ROOT,
+    platforms: tuple[str, ...] = PLATFORMS,
+    bench_dir: Path = BENCH_DIR,
+) -> int:
+    """One run of a cell; ``bench_dir`` is where its traffic, generator and
+    metric files are found."""
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -246,25 +256,25 @@ def main(argv: list[str] | None = None, *, root: Path = ROOT, platforms: tuple[s
 
     from harness.manifest import load_cell, read_metrics
 
-    cell = load_cell(root, args.workload)
+    cell = load_cell(root, args.workload, bench_dir)
     device = check_device(cell.chips, platforms)
     enable_compile_cache()
 
     import jax
 
-    from harness import reference
+    from harness import graphs, reference
     from harness.hooks import CompileCounter, Overdue, TimedBackend
-    from harness.kronecker import kronecker_edges, vertex_map
     from harness.traffic import QueryStream
     from repro.core import PRESETS, EngineConfig, MultiQueryEngine, PallasBackend
     from repro.graph.structure import build_graph
 
     counter = CompileCounter()
     config, traffic = cell.config, cell.traffic
-    src, dst = kronecker_edges(config, args.seed)
-    nv = 1 << int(config["SCALE"])
+    generator = graphs.load(config, bench_dir)
+    src, dst = generator.edges(config, args.seed)
+    nv = generator.num_vertices(config)
     host_graph = reference.Graph(src, dst, nv)
-    vmap = vertex_map(config, args.seed)
+    vmap = generator.vertex_map(config, args.seed)
     graph = build_graph(src, dst, nv, name=cell.config_name)
     run = Run(config, args.seconds, device, nv, host_graph.num_edges)
 
@@ -359,7 +369,7 @@ def main(argv: list[str] | None = None, *, root: Path = ROOT, platforms: tuple[s
         file=sys.stderr,
     )
 
-    metrics = read_metrics(cell.per_layer if args.trace else cell.end_to_end, run)
+    metrics = read_metrics(cell.per_layer if args.trace else cell.end_to_end, run, bench_dir)
     result = {"correct": correct, "attempted": attempted, "failed": failed, "metrics": metrics, "device": device}
     if run.trace is not None:
         from harness.trace import breakdown

@@ -11,7 +11,7 @@ def _dc_module():
     # the package re-exports a function under the submodule's name
     return importlib.import_module("repro.kernels.degree_count.degree_count")
 
-from conftest import run_cell
+from conftest import TINY_CELLS, run_cell
 
 
 def _state_unchanged(monkeypatch):
@@ -65,13 +65,13 @@ def _answer_altered(monkeypatch):
 FAULTS = {"state_unchanged": _state_unchanged, "half_left_out": _half_left_out, "answer_altered": _answer_altered}
 
 
-@pytest.mark.parametrize("traffic", ["pr-4s", "mix-16s"])
+@pytest.mark.parametrize("workload", sorted(TINY_CELLS))
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_fault_makes_the_run_incorrect(tiny_root, capsys, monkeypatch, fault, traffic):
+def test_fault_makes_the_run_incorrect(tiny_root, capsys, monkeypatch, fault, workload):
     import run
 
     FAULTS[fault](monkeypatch)
-    rc, result, err = run_cell(tiny_root, f"tiny.{traffic}", capsys, seed=23, seconds=1.0)
+    rc, result, err = run_cell(tiny_root, workload, capsys, seed=23, seconds=1.0)
     assert rc == 0, err
     assert result["correct"] is False
     assert result["failed"] > 0

@@ -1,11 +1,12 @@
 """``BENCHMARK.json`` against the benchmark's contract, and the harness
-finding a configuration, mix or metric that is new files alone."""
+finding a configuration, generator, mix or metric that is new files alone."""
 import json
 import re
 
+import numpy as np
 import pytest
 
-from conftest import BENCH_DIR, REPO
+from conftest import BENCH_DIR, REPO, add_grid_generator, grid_config
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -50,6 +51,9 @@ def test_every_cell_has_its_files(manifest):
         data = json.loads((REPO / c["file"]).read_text())
         assert data["name"] == c["name"]
         assert set(c["reduced"]) == set(data["reduced"])
+        assert "structure_seed" in data  # BFS roots are drawn with it
+        if "generator" in data:
+            assert (BENCH_DIR / "generators" / f"{data['generator']}.py").is_file(), data["generator"]
         assert any(w["config"] == c["name"] for w in manifest["workloads"])
     for m in manifest["end_to_end"] + manifest["per_layer"]:
         assert (BENCH_DIR / "metrics" / f"{m['name']}.py").is_file(), m["name"]
@@ -86,7 +90,9 @@ def test_per_layer_metrics_move_what_their_cells_report(manifest):
 
 def test_new_config_mix_and_metric_are_picked_up_from_new_files(tmp_path, bench_copy):
     """A new configuration, traffic mix and per-layer metric, added as new
-    files and new manifest entries only, are found by name."""
+    files and new manifest entries only, are found by name; so is a new
+    configuration's generator, a new file in ``generators``."""
+    from harness import graphs
     from harness.manifest import load_cell, read_metrics
 
     root = tmp_path
@@ -94,23 +100,23 @@ def test_new_config_mix_and_metric_are_picked_up_from_new_files(tmp_path, bench_
     config = json.loads((REPO / manifest["configs"][-1]["file"]).read_text())
     config["name"] = "g500-s14"
     (bench_copy / "configs" / "g500-s14.json").write_text(json.dumps(config))
-    (bench_copy / "traffic" / "pr-1s.json").write_text(
-        json.dumps({"name": "pr-1s", "sessions": 1, "mix": {"pagerank_pull": 1}})
+    (bench_copy / "traffic" / "pr-2s.json").write_text(
+        json.dumps({"name": "pr-2s", "sessions": 2, "mix": {"pagerank_pull": 2}})
     )
     (bench_copy / "metrics" / "steps_in_window.py").write_text(
         "def read(run):\n    return len(run.window_steps())\n"
     )
     manifest["configs"].append(dict(manifest["configs"][1], name="g500-s14", file="bench/configs/g500-s14.json"))
-    manifest["workloads"].append({"name": "g500-s14.pr-1s", "config": "g500-s14", "traffic": "pr-1s", "chips": 1, "why": "x"})
+    manifest["workloads"].append({"name": "g500-s14.pr-2s", "config": "g500-s14", "traffic": "pr-2s", "chips": 1, "why": "x"})
     manifest["per_layer"].append({
         "name": "steps_in_window", "unit": "steps", "better": "higher", "source": "host_clock",
-        "layer": "engine host loop", "moves": "edges_per_s", "workloads": ["g500-s14.pr-1s"],
+        "layer": "engine host loop", "moves": "edges_per_s", "workloads": ["g500-s14.pr-2s"],
     })
     (root / "BENCHMARK.json").write_text(json.dumps(manifest))
 
-    cell = load_cell(root, "g500-s14.pr-1s", bench_dir=bench_copy)
+    cell = load_cell(root, "g500-s14.pr-2s", bench_dir=bench_copy)
     assert cell.config["name"] == "g500-s14"
-    assert cell.traffic["mix"] == {"pagerank_pull": 1}
+    assert cell.traffic["mix"] == {"pagerank_pull": 2}
     assert [m.name for m in cell.per_layer] == ["steps_in_window"]
     assert [m.name for m in cell.end_to_end] == ["edges_per_s", "setup_s"]
 
@@ -121,3 +127,21 @@ def test_new_config_mix_and_metric_are_picked_up_from_new_files(tmp_path, bench_
     assert read_metrics(cell.per_layer, FakeRun(), bench_dir=bench_copy) == {
         "steps_in_window": {"value": 3.0, "unit": "steps"}
     }
+
+    add_grid_generator(bench_copy)
+    (bench_copy / "configs" / "grid32.json").write_text(json.dumps(grid_config()))
+    manifest["configs"].append(dict(manifest["configs"][1], name="grid32", file="bench/configs/grid32.json"))
+    manifest["workloads"].append({"name": "grid32.pr-2s", "config": "grid32", "traffic": "pr-2s", "chips": 1, "why": "x"})
+    (root / "BENCHMARK.json").write_text(json.dumps(manifest))
+    cell = load_cell(root, "grid32.pr-2s", bench_dir=bench_copy)
+    generator = graphs.load(cell.config, bench_copy)
+    assert generator.__file__ == str(bench_copy / "generators" / "grid.py")
+    src, dst = generator.edges(cell.config, 2**33 + 1)
+    assert generator.num_vertices(cell.config) == 1024
+    assert src.size == dst.size == 2 * 2 * 32 * 31  # both directions of every grid edge
+    assert np.bincount(src, minlength=1024).max() == 4
+    vmap = generator.vertex_map(cell.config, 2**33 + 1)
+    assert sorted(vmap) == list(range(1024))
+    assert generator.num_vertices(generator.small(cell.config, 6)) == 64
+    with pytest.raises(FileNotFoundError):
+        graphs.load(dict(cell.config, generator="absent"), bench_copy)

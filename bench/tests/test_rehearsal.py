@@ -1,15 +1,14 @@
-"""A CPU rehearsal of ``run.py`` at SCALE 10 with interpreted kernels."""
+"""A CPU rehearsal of ``run.py`` on the tiny graph with interpreted kernels."""
 import json
 
 import pytest
 
-from conftest import run_cell
+from conftest import TINY_CELLS, run_cell
 
 
-@pytest.mark.parametrize("traffic", ["pr-4s", "mix-16s"])
+@pytest.mark.parametrize("workload", sorted(TINY_CELLS))
 @pytest.mark.parametrize("trace", [0, 1])
-def test_cell_runs_and_is_correct(tiny_root, capsys, traffic, trace):
-    workload = f"tiny.{traffic}"
+def test_cell_runs_and_is_correct(tiny_root, capsys, workload, trace):
     # a window that finishes whole batches of the undirected graph's BFS
     # on a loaded CPU, so the latency tail has samples
     rc, result, err = run_cell(tiny_root, workload, capsys, seed=2**31 + 17, seconds=6.0, trace=trace)
@@ -34,12 +33,13 @@ def test_cell_runs_and_is_correct(tiny_root, capsys, traffic, trace):
 
 def test_same_seed_same_inputs(tiny_root):
     import run  # noqa: F401  (puts the harness on the path)
-    from harness.kronecker import kronecker_edges
+    from harness import graphs
 
     config = json.loads((tiny_root / "tiny.json").read_text())
-    a = kronecker_edges(config, 2**33 + 5)
-    b = kronecker_edges(config, 2**33 + 5)
-    c = kronecker_edges(config, 2**33 + 6)
+    edges = graphs.load(config).edges
+    a = edges(config, 2**33 + 5)
+    b = edges(config, 2**33 + 5)
+    c = edges(config, 2**33 + 6)
     assert all((x == y).all() for x, y in zip(a, b))
     assert not (a[0] == c[0]).all()
 
@@ -48,7 +48,7 @@ def test_refuses_a_platform_that_is_not_a_tpu(tiny_root, capsys):
     import run
 
     with pytest.raises(SystemExit) as exc:
-        run.main(["--workload", "tiny.pr-4s", "--seed", "1", "--seconds", "1"], root=tiny_root)
+        run.main(["--workload", sorted(TINY_CELLS)[0], "--seed", "1", "--seconds", "1"], root=tiny_root)
     assert exc.value.code == 3
     out, err = capsys.readouterr()
     assert out == "" and "nothing was run" in err
